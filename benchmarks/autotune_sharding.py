@@ -42,9 +42,9 @@ import sys
 import time
 from pathlib import Path
 
-import repro.compat  # noqa: F401  (pins JAX_PLATFORMS=cpu on bare runners)
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, get_shape
 from repro.core import Tuner, ParamSpace, Choice, LogInt, CHOICE_KEY
 from repro.launch.hlo_cost import estimate_plan
@@ -162,13 +162,11 @@ def run_sharding(args):
 def validate_with_dryrun(args, plan):
     """Re-score the winner through the real lower+compile pipeline.
 
-    The subprocess inherits the parent environment (plus a defaulted
-    JAX_PLATFORMS) — a scrubbed env used to drop JAX_PLATFORMS, which let
-    the TPU plugin stall on GCP metadata lookups on bare CI runners.
+    The subprocess inherits the parent environment unchanged (plus
+    PYTHONPATH): the caller's ``JAX_PLATFORMS`` decides its platform.
     """
     tag = "autotune-best"
     env = {**os.environ, "PYTHONPATH": str(ROOT / "src")}
-    env.setdefault("JAX_PLATFORMS", "cpu")
     cmd = [sys.executable, "-m", "repro.launch.dryrun",
            "--arch", args.arch, "--shape", args.shape, "--mesh", "single",
            "--tag", tag, "--out", str(OUT),
@@ -229,7 +227,6 @@ def run_kernels(args):
     # default-tile runtime (so branches are comparable and the argmax is
     # "which kernel gains most from retiling, and with which tiles")
     t_flash0 = _measure(lambda: (lambda: sdpa(q, k, v, causal=True,
-                                              interpret=True,
                                               block_q=128, block_k=128)))
     t_ssm0 = _measure(lambda: (lambda: selective_scan(
         A, Bx, Cc, block_d=min(512, di), chunk=64)))
@@ -254,8 +251,7 @@ def run_kernels(args):
             if kc[CHOICE_KEY] == "flash_attention":
                 bq, bk = kc["block_q"], kc["block_k"]
                 t = _measure(lambda: (lambda: sdpa(
-                    q, k, v, causal=True, interpret=True,
-                    block_q=bq, block_k=bk)))
+                    q, k, v, causal=True, block_q=bq, block_k=bk)))
                 rel = t / t_flash0
             else:
                 bd, ck = kc["block_d"], kc["chunk"]
@@ -296,6 +292,7 @@ def main():
                          "lower+compile dry-run (minutes)")
     ap.add_argument("--skip-kernels", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
     if not args.full:
         args.quick = True
     OUT.mkdir(parents=True, exist_ok=True)

@@ -216,6 +216,8 @@ def main():
                     help="also write every emitted row as JSON (the CI "
                          "tier-2 job uploads this as BENCH_*.json)")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     results = run_throughput(reps=args.reps)
     retraces = run_retrace_sweep(max_obs=256 if args.quick else 1024)
     target = [s for B, s in results if B == 256]

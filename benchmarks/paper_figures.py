@@ -132,6 +132,8 @@ def main():
     ap.add_argument("--json", type=str, default=None, metavar="PATH",
                     help="also write traces + claim verdicts as JSON")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     grid = "quick" if args.quick else ("full" if args.full else "default")
     figs = ["fig2", "fig3"] if args.fig == "all" else [f"fig{args.fig}"]
     run_figures(figs, grid=grid, json_path=args.json)

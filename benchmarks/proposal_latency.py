@@ -692,6 +692,8 @@ def main():
                     help="also write every emitted row as JSON (the CI "
                          "tier-2 job uploads this as BENCH_*.json)")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.quick:
         rows = run(batch_sizes=(4,), n_obs_grid=(64, 256), reps=args.reps)
         run_pallas_pending(n_obs_grid=(64,), reps=args.reps)

@@ -54,6 +54,8 @@ def main(argv=None) -> int:
     ap.add_argument("--json", default=None)
     ap.add_argument("--calls", type=int, default=20)
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.core.studybank import StudyBank
     from repro.service.client import ServiceClient

@@ -104,7 +104,8 @@ def no_retrace(jits: Optional[Mapping[str, object]] = None,
 @contextlib.contextmanager
 def no_transfer(device_to_host: Optional[str] = "disallow",
                 host_to_device: Optional[str] = None,
-                device_to_device: Optional[str] = None):
+                device_to_device: Optional[str] = None,
+                all_threads: bool = False):
     """Transfer-guard the block.  Levels per direction: None (leave
     unchanged), "allow", "log", "disallow", "log_explicit",
     "disallow_explicit" — see ``jax.transfer_guard``.
@@ -116,6 +117,11 @@ def no_transfer(device_to_host: Optional[str] = "disallow",
     ``host_to_device="disallow"`` too when auditing a fully
     device-resident region.
 
+    JAX's guard contexts hold for the calling thread only.  With
+    ``all_threads=True`` the levels are set process-wide for the block
+    instead (and restored after), which is what auditing a server whose
+    handler threads run the device work needs.
+
     Backend caveat: on the CPU backend device buffers live in host
     memory, so device->host reads are zero-copy and the d2h guard can
     never fire — it becomes load-bearing on accelerator backends.  The
@@ -123,16 +129,29 @@ def no_transfer(device_to_host: Optional[str] = "disallow",
     tests pin the implicit-raises / explicit-allowed split there).
     """
     import jax
+    levels = {"jax_transfer_guard_device_to_host": device_to_host,
+              "jax_transfer_guard_host_to_device": host_to_device,
+              "jax_transfer_guard_device_to_device": device_to_device}
+    levels = {k: v for k, v in levels.items() if v is not None}
+    if all_threads:
+        prev = {k: getattr(jax.config, k) for k in levels}
+        try:
+            for k, v in levels.items():
+                jax.config.update(k, v)
+            yield
+        finally:
+            for k, v in prev.items():
+                jax.config.update(k, v)
+        return
+    guards = {"jax_transfer_guard_device_to_host":
+              jax.transfer_guard_device_to_host,
+              "jax_transfer_guard_host_to_device":
+              jax.transfer_guard_host_to_device,
+              "jax_transfer_guard_device_to_device":
+              jax.transfer_guard_device_to_device}
     with contextlib.ExitStack() as stack:
-        if device_to_host is not None:
-            stack.enter_context(
-                jax.transfer_guard_device_to_host(device_to_host))
-        if host_to_device is not None:
-            stack.enter_context(
-                jax.transfer_guard_host_to_device(host_to_device))
-        if device_to_device is not None:
-            stack.enter_context(
-                jax.transfer_guard_device_to_device(device_to_device))
+        for k, v in levels.items():
+            stack.enter_context(guards[k](v))
         yield
 
 

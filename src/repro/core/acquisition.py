@@ -44,7 +44,7 @@ def ucb(mu: np.ndarray, sigma: np.ndarray, beta: float) -> np.ndarray:
 
 @functools.partial(jax.jit, static_argnames=("batch_size", "n_top",
                                              "pend_cap", "use_pallas",
-                                             "block_s", "interpret"))
+                                             "block_s"))
 def fused_cluster_propose(X: jax.Array, y: jax.Array, mask: jax.Array,
                           L: jax.Array, Linv: jax.Array, P: jax.Array,
                           n_pending: jax.Array,
@@ -52,8 +52,7 @@ def fused_cluster_propose(X: jax.Array, y: jax.Array, mask: jax.Array,
                           domain_size: jax.Array, key,
                           batch_size: int, n_top: int,
                           pend_cap: int, use_pallas: bool = False,
-                          block_s: int = 256,
-                          interpret: bool = True) -> jax.Array:
+                          block_s: int = 256) -> jax.Array:
     """Device-resident clustering batch proposal: one program per ask.
 
     1. Absorb the (padded, ``pend_cap``) pending buffer through the shared
@@ -89,7 +88,7 @@ def fused_cluster_propose(X: jax.Array, y: jax.Array, mask: jax.Array,
 
     mu, sig2, _, _ = scoring.posterior_scores(
         Cs, Xs, y, mask, Linv, var, noise, use_pallas=use_pallas,
-        block_s=block_s, interpret=interpret)
+        block_s=block_s)
     beta = scoring.adaptive_beta_dev(n_obs + n_pending, domain_size)
     acq = mu + jnp.sqrt(beta) * jnp.sqrt(sig2)
     acq = jnp.where(jnp.arange(Cs.shape[0]) < S, acq, -jnp.inf)

@@ -45,7 +45,7 @@ class AsyncTuner:
                  mc_samples: Optional[int] = None,
                  poll_interval: float = 0.01, refit_every: int = 8,
                  optimizer: str = "bayesian", fit_steps: int = 40,
-                 use_pallas: bool = False, pallas_interpret: bool = True,
+                 use_pallas: bool = False,
                  domain_size: Optional[float] = None,
                  early_stopping: Optional[Callable[[TunerResults], bool]]
                  = None,
@@ -70,7 +70,7 @@ class AsyncTuner:
                 param_space, optimizer=optimizer, seed=seed,
                 domain_size=domain_size, mc_samples=mc_samples,
                 fit_steps=fit_steps, use_pallas=use_pallas,
-                pallas_interpret=pallas_interpret, refit_every=refit_every,
+                refit_every=refit_every,
                 strategy_kwargs=strategy_kwargs)
         self.space = self.opt.space
         if checkpoint_path and Path(checkpoint_path).exists():

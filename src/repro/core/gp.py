@@ -339,12 +339,12 @@ def fused_propose_pending(X: jax.Array, y: jax.Array, mask: jax.Array,
 
 
 @functools.partial(jax.jit, static_argnames=("batch_size", "block_s",
-                                             "interpret", "use_pallas"))
+                                             "use_pallas"))
 def fused_propose_pallas(X: jax.Array, y: jax.Array, mask: jax.Array,
                          L: jax.Array, Linv: jax.Array, C: jax.Array,
                          ls, var, noise, n_obs: jax.Array,
                          domain_size: jax.Array, batch_size: int,
-                         block_s: int = 256, interpret: bool = True,
+                         block_s: int = 256,
                          use_pallas: bool = True) -> jax.Array:
     """``fused_propose`` on the shared conditioning-hardened scoring core.
 
@@ -362,12 +362,11 @@ def fused_propose_pallas(X: jax.Array, y: jax.Array, mask: jax.Array,
     return scoring.pick_downdate_loop(
         Cs, Xs, S, y.astype(jnp.float32), mask.astype(jnp.float32), L,
         Linv, var, noise, n_obs, domain_size, batch_size,
-        use_pallas=use_pallas, block_s=block_s, interpret=interpret)
+        use_pallas=use_pallas, block_s=block_s)
 
 
 @functools.partial(jax.jit, static_argnames=("batch_size", "pend_cap",
-                                             "block_s", "interpret",
-                                             "use_pallas"))
+                                             "block_s", "use_pallas"))
 def fused_propose_pallas_pending(X: jax.Array, y: jax.Array,
                                  mask: jax.Array, L: jax.Array,
                                  Linv: jax.Array, P: jax.Array,
@@ -375,7 +374,6 @@ def fused_propose_pallas_pending(X: jax.Array, y: jax.Array,
                                  ls, var, noise, n_obs: jax.Array,
                                  domain_size: jax.Array, batch_size: int,
                                  pend_cap: int, block_s: int = 256,
-                                 interpret: bool = True,
                                  use_pallas: bool = True) -> jax.Array:
     """``fused_propose_pallas`` with in-flight trials absorbed *inside* the
     program (the async replacement-pick hot path on the shared core).
@@ -397,8 +395,7 @@ def fused_propose_pallas_pending(X: jax.Array, y: jax.Array,
         Xs, y, mask, L, Linv, Ps, n_pending, n_obs, var, noise, pend_cap)
     return scoring.pick_downdate_loop(
         Cs, Xs, S, y, mask, L, Linv, var, noise, n_obs + n_pending,
-        domain_size, batch_size, use_pallas=use_pallas, block_s=block_s,
-        interpret=interpret)
+        domain_size, batch_size, use_pallas=use_pallas, block_s=block_s)
 
 
 # --------------------------------------------------------------------------- #
@@ -426,7 +423,28 @@ def fused_propose_pallas_pending(X: jax.Array, y: jax.Array,
 # and f32 elementwise/dot ops produce identical bits whether or not they
 # share a fusion region — verified empirically against ``score_cov_ref``
 # and exercised by the bank-vs-single pick-parity suite.
+def _f32_matmuls(fn):
+    """Trace ``fn`` with float32 matrix products (``"highest"`` precision).
+
+    On the TPU a float32 ``dot`` at default precision runs as one bf16
+    pass, about three significant digits.  The GP core cancels in two
+    places: squared distances by the expansion ``|c|² + |x|² − 2 c·x``,
+    and the variance as ``var + noise − ‖K L⁻ᵀ‖²``.  Measured on a v5e at
+    default precision, the hyperparameter fit diverged to non-finite
+    values, and with the fit repaired the posterior still carried 4e-2 to
+    1.7e-1 of error (standardized units); with float32 products every
+    stage agrees with a float64 reference within 3e-4.  On the CPU a
+    float32 dot is float32 at any precision, so results are unchanged
+    there."""
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+    return traced
+
+
 @jax.jit
+@_f32_matmuls
 def bank_factors(X: jax.Array, mask: jax.Array, ls, var, noise):
     """Masked-kernel Cholesky factors for every study: (B, na, d) ->
     ``(L, Linv, cond)`` at (B, na, na) / (B,).  Deterministic from ledger
@@ -479,6 +497,7 @@ def bank_prescale_C(C: jax.Array, ls: jax.Array) -> jax.Array:
 
 
 @functools.partial(jax.jit, static_argnames=("pend_cap",))
+@_f32_matmuls
 def bank_absorb(Xs: jax.Array, y: jax.Array, mask: jax.Array,
                 L: jax.Array, Linv: jax.Array, P: jax.Array,
                 n_pending: jax.Array, n_obs: jax.Array,
@@ -501,6 +520,7 @@ def bank_absorb(Xs: jax.Array, y: jax.Array, mask: jax.Array,
 
 
 @jax.jit
+@_f32_matmuls
 def bank_dist(Cs: jax.Array, Xs: jax.Array):
     """Pairwise squared distances and the Matern argument ``s = sqrt(5) r``
     for every study: (B, Sp, dp) x (B, na, dp) -> (d2, s) at (B, Sp, na).
@@ -523,7 +543,23 @@ def bank_exp(s: jax.Array) -> jax.Array:
     return jnp.exp(-s)
 
 
+@_f32_matmuls
+def bank_scores(d2, s, e, y, mask, Linv, var, noise):
+    """One study's posterior over its candidates from the staged pieces:
+    the masked Matern block ``K`` (S, na), the mean ``K K⁻¹y`` and the
+    variance as the conditioning-hardened sum of squares
+    ``var + noise − ‖K L⁻ᵀ‖²``.  Returns ``(mu, sig2, K)``; both pick
+    heads score through it."""
+    K = var * (1.0 + s + (5.0 / 3.0) * d2) * e * mask[None, :]
+    alpha = scoring.kinv_matvec(Linv, y * mask)
+    mu = K @ alpha
+    t = K @ Linv.T
+    q = jnp.sum(t * t, axis=-1)
+    return mu, jnp.maximum(var + noise - q, 1e-10), K
+
+
 @functools.partial(jax.jit, static_argnames=("batch_size", "S"))
+@_f32_matmuls
 def bank_pick(d2: jax.Array, s: jax.Array, e: jax.Array, Cs: jax.Array,
               y: jax.Array, mask: jax.Array, L: jax.Array,
               Linv: jax.Array, var, noise, n_obs_eff: jax.Array,
@@ -535,12 +571,7 @@ def bank_pick(d2: jax.Array, s: jax.Array, e: jax.Array, Cs: jax.Array,
     Returns picked candidate indices (B, batch_size)."""
 
     def one(d2, s, e, Cs, y, mask, L, Linv, var, noise, n_obs_eff):
-        K = var * (1.0 + s + (5.0 / 3.0) * d2) * e * mask[None, :]
-        alpha = scoring.kinv_matvec(Linv, y * mask)
-        mu = K @ alpha
-        t = K @ Linv.T
-        q = jnp.sum(t * t, axis=-1)
-        sig2 = jnp.maximum(var + noise - q, 1e-10)
+        mu, sig2, K = bank_scores(d2, s, e, y, mask, Linv, var, noise)
         return scoring.pick_downdate_from_scores(
             Cs, S, mu, sig2, K, L, Linv, var, noise, n_obs_eff,
             domain_size, batch_size, use_pallas=False)
@@ -550,6 +581,7 @@ def bank_pick(d2: jax.Array, s: jax.Array, e: jax.Array, Cs: jax.Array,
 
 
 @functools.partial(jax.jit, static_argnames=("batch_size", "n_top", "S"))
+@_f32_matmuls
 def bank_cluster_pick(d2: jax.Array, s: jax.Array, e: jax.Array,
                       C: jax.Array, y: jax.Array, mask: jax.Array,
                       Linv: jax.Array, var, noise, n_obs_eff: jax.Array,
@@ -567,12 +599,7 @@ def bank_cluster_pick(d2: jax.Array, s: jax.Array, e: jax.Array,
     from repro.core.kmeans import _kmeans
 
     def one(d2, s, e, C, y, mask, Linv, var, noise, n_obs_eff, key):
-        K = var * (1.0 + s + (5.0 / 3.0) * d2) * e * mask[None, :]
-        alpha = scoring.kinv_matvec(Linv, y * mask)
-        mu = K @ alpha
-        t = K @ Linv.T
-        q = jnp.sum(t * t, axis=-1)
-        sig2 = jnp.maximum(var + noise - q, 1e-10)
+        mu, sig2, _ = bank_scores(d2, s, e, y, mask, Linv, var, noise)
         beta = adaptive_beta_dev(n_obs_eff, domain_size)
         acq = mu + jnp.sqrt(beta) * jnp.sqrt(sig2)
         acq = jnp.where(jnp.arange(C.shape[0]) < S, acq, -jnp.inf)
@@ -599,6 +626,7 @@ def bank_cluster_pick(d2: jax.Array, s: jax.Array, e: jax.Array,
 
 
 @functools.partial(jax.jit, static_argnames=("steps",))
+@_f32_matmuls
 def fit_hypers_bank(X: jax.Array, y: jax.Array, mask: jax.Array,
                     log_ls: jax.Array, log_var: jax.Array,
                     log_noise: jax.Array, y_mean: jax.Array,

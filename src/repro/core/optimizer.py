@@ -168,7 +168,7 @@ class AskTellOptimizer:
                  seed: int = 0, sign: float = 1.0,
                  domain_size: Optional[float] = None,
                  mc_samples: Optional[int] = None, fit_steps: int = 40,
-                 use_pallas: bool = False, pallas_interpret: bool = True,
+                 use_pallas: bool = False,
                  refit_every: int = 8,
                  strategy_kwargs: Optional[Dict[str, Any]] = None,
                  ledger: Optional[StudyLedger] = None,
@@ -182,7 +182,6 @@ class AskTellOptimizer:
         self.mc_samples = mc_samples
         self.fit_steps = fit_steps
         self.use_pallas = use_pallas
-        self.pallas_interpret = pallas_interpret
         self.refit_every = refit_every
         # strategy-specific knobs (e.g. tpe's gamma/pending_penalty,
         # clustering's top_frac) forwarded verbatim to the constructor —
@@ -280,7 +279,6 @@ class AskTellOptimizer:
             self._strat = cls(self.space.dim, self.domain_size,
                               fit_steps=self.fit_steps,
                               use_pallas=self.use_pallas,
-                              pallas_interpret=self.pallas_interpret,
                               refit_every=self.refit_every,
                               **self.strategy_kwargs)
             if self.optimizer not in _BANKABLE:

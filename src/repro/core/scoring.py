@@ -216,8 +216,7 @@ def kinv_matvec(Linv: jax.Array, v: jax.Array) -> jax.Array:
 # --------------------------------------------------------------------------- #
 def posterior_scores(Cs: jax.Array, Xs: jax.Array, y: jax.Array,
                      mask: jax.Array, Linv: jax.Array, var, noise, *,
-                     use_pallas: bool, block_s: int = 256,
-                     interpret: bool = True):
+                     use_pallas: bool, block_s: int = 256):
     """(mu, sig2, Kc, alpha) for pre-scaled candidates Cs against the
     pre-scaled training set (Xs, mask) through the factor Linv.
 
@@ -230,8 +229,7 @@ def posterior_scores(Cs: jax.Array, Xs: jax.Array, y: jax.Array,
     alpha = kinv_matvec(Linv, y * mask)
     if use_pallas:
         mu, sig2, Kc = score_cov_pallas(Cs, Xs, mask, Linv, alpha, var,
-                                        noise, block_s=block_s,
-                                        interpret=interpret)
+                                        noise, block_s=block_s)
     else:
         mu, sig2, Kc = score_cov_ref(Cs, Xs, mask, Linv, alpha,
                                      jnp.float32(1.0), var, noise)
@@ -239,14 +237,14 @@ def posterior_scores(Cs: jax.Array, Xs: jax.Array, y: jax.Array,
 
 
 def var_downdate(Cs, x_star, Kc, u, schur, sig2, var, *, use_pallas: bool,
-                 block_s: int = 256, interpret: bool = True):
+                 block_s: int = 256):
     """Rank-1 variance downdate after absorbing x*: kernel or jnp twin."""
     from repro.kernels.gp_acquisition.gp_acquisition import \
         var_downdate_pallas
 
     if use_pallas:
         return var_downdate_pallas(Cs, x_star, Kc, u, schur, sig2, var,
-                                   block_s=block_s, interpret=interpret)
+                                   block_s=block_s)
     return var_downdate_ref(Cs, x_star, Kc, u, schur, sig2,
                             jnp.float32(1.0), var)
 
@@ -291,8 +289,7 @@ def pick_downdate_loop(Cs: jax.Array, Xs: jax.Array, S: int, y: jax.Array,
                        mask: jax.Array, L: jax.Array, Linv: jax.Array,
                        var, noise, n_obs: jax.Array,
                        domain_size: jax.Array, batch_size: int, *,
-                       use_pallas: bool, block_s: int = 256,
-                       interpret: bool = True) -> jax.Array:
+                       use_pallas: bool, block_s: int = 256) -> jax.Array:
     """GP-BUCB slot loop on the shared scorer with O(n S) per-slot rescores.
 
     One ``posterior_scores`` pass scores every candidate *and* caches the
@@ -309,11 +306,10 @@ def pick_downdate_loop(Cs: jax.Array, Xs: jax.Array, S: int, y: jax.Array,
 
     mu, sig2, Kc, _ = scoring.posterior_scores(
         Cs, Xs, y, mask, Linv, var, noise, use_pallas=use_pallas,
-        block_s=block_s, interpret=interpret)
+        block_s=block_s)
     return pick_downdate_from_scores(
         Cs, S, mu, sig2, Kc, L, Linv, var, noise, n_obs, domain_size,
-        batch_size, use_pallas=use_pallas, block_s=block_s,
-        interpret=interpret)
+        batch_size, use_pallas=use_pallas, block_s=block_s)
 
 
 def pick_downdate_from_scores(Cs: jax.Array, S: int, mu: jax.Array,
@@ -321,8 +317,7 @@ def pick_downdate_from_scores(Cs: jax.Array, S: int, mu: jax.Array,
                               Linv: jax.Array, var, noise,
                               n_obs: jax.Array, domain_size: jax.Array,
                               batch_size: int, *, use_pallas: bool,
-                              block_s: int = 256,
-                              interpret: bool = True) -> jax.Array:
+                              block_s: int = 256) -> jax.Array:
     """The slot loop of ``pick_downdate_loop`` given an already-scored
     candidate set — op-for-op the same program, split out so the staged
     bank pipeline (``gp.bank_pick``) can feed scores whose Matern ``exp``
@@ -349,7 +344,7 @@ def pick_downdate_from_scores(Cs: jax.Array, S: int, mu: jax.Array,
         L, Linv, u, schur = factor_append(L, Linv, slot, k_vec, var, noise)
         sig2, k_new = scoring.var_downdate(
             Cs, Cs[idx], Kc, u, schur, sig2, var, use_pallas=use_pallas,
-            block_s=block_s, interpret=interpret)
+            block_s=block_s)
         Kc = Kc.at[:, slot].set(k_new)
         return L, Linv, Kc, sig2, avail, picks
 

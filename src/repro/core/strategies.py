@@ -71,7 +71,7 @@ class BaseStrategy:
     needs_gp = True
 
     def __init__(self, dim: int, domain_size: float, fit_steps: int = 40,
-                 use_pallas: bool = False, pallas_interpret: bool = True,
+                 use_pallas: bool = False,
                  refit_every: int = 8, scorer: Optional[str] = None):
         self._scorer_explicit = scorer is not None
         if scorer is None:
@@ -91,7 +91,6 @@ class BaseStrategy:
                                   refit_every=refit_every,
                                   track_factor=scorer != "chol")
         self.domain_size = domain_size
-        self.pallas_interpret = pallas_interpret
         self._cond_src = None
         self._cond_warned = False
 
@@ -125,8 +124,7 @@ class BaseStrategy:
     def _predict(self, st, C: np.ndarray):
         if self.use_pallas:
             from repro.kernels.gp_acquisition import ops as gp_ops
-            return gp_ops.gp_mean_std(st, C,
-                                      interpret=self.pallas_interpret)
+            return gp_ops.gp_mean_std(st, C)
         return self.gp.predict(C, st)
 
     def _absorb_pending(self, st, pending):
@@ -216,12 +214,11 @@ class FusedHallucinationStrategy(BaseStrategy):
                 *args, st.L[:na, :na], st.Linv[:na, :na],
                 jnp.asarray(P), jnp.int32(n_pend), *tail,
                 batch_size=batch_size, pend_cap=cap,
-                interpret=self.pallas_interpret,
                 use_pallas=self.use_pallas)
         elif self.scorer != "chol":
             picks = gp_lib.fused_propose_pallas(
                 *args, st.L[:na, :na], st.Linv[:na, :na], *tail,
-                batch_size=batch_size, interpret=self.pallas_interpret,
+                batch_size=batch_size,
                 use_pallas=self.use_pallas)
         elif n_pend:
             picks = gp_lib.fused_propose_pending(
@@ -295,8 +292,7 @@ class ClusteringStrategy(BaseStrategy):
             st.ls, st.var, st.noise, jnp.int32(st.n),
             jnp.float32(self.domain_size), jax.random.PRNGKey(seed),
             batch_size=batch_size, n_top=self._n_top(S, batch_size),
-            pend_cap=cap, use_pallas=self.use_pallas,
-            interpret=self.pallas_interpret)
+            pend_cap=cap, use_pallas=self.use_pallas)
         return [int(i) for i in np.asarray(picks)]
 
     def propose_host(self, X, y, candidates, batch_size, seed=0,

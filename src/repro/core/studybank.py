@@ -297,7 +297,7 @@ class StudyBank:
                  optimizer=None, seed: int = 0,
                  sign: float = 1.0, domain_size: Optional[float] = None,
                  mc_samples: Optional[int] = None, fit_steps: int = 40,
-                 use_pallas: bool = False, pallas_interpret: bool = True,
+                 use_pallas: bool = False,
                  refit_every: int = 8,
                  strategy_kwargs: Optional[Dict[str, Any]] = None):
         from repro.core.optimizer import AskTellOptimizer
@@ -318,7 +318,6 @@ class StudyBank:
         self.mc_samples = mc_samples
         self.fit_steps = fit_steps
         self.use_pallas = use_pallas
-        self.pallas_interpret = pallas_interpret
         self.refit_every = refit_every
         self.strategy_kwargs = dict(strategy_kwargs or {})
         self.seed = seed
@@ -337,7 +336,6 @@ class StudyBank:
                              seed=seed + 1 + i, sign=sign,
                              domain_size=domain_size, mc_samples=mc_samples,
                              fit_steps=fit_steps, use_pallas=use_pallas,
-                             pallas_interpret=pallas_interpret,
                              refit_every=refit_every,
                              strategy_kwargs=strategy_kwargs,
                              ledger=self.ledger, study_index=i)
@@ -360,7 +358,6 @@ class StudyBank:
         bank.mc_samples = view.mc_samples
         bank.fit_steps = view.fit_steps
         bank.use_pallas = view.use_pallas
-        bank.pallas_interpret = view.pallas_interpret
         bank.refit_every = view.refit_every
         bank.strategy_kwargs = dict(view.strategy_kwargs)
         bank.seed = None
@@ -881,7 +878,7 @@ class StudyBank:
                          np.full((R,), gamma, np.float32)], axis=1)
         return tpe_lib.fused_tpe_propose_bank(
             Xt, yt, Ct, meta, batch_size=n, d_true=d,
-            use_pallas=False, interpret=self.pallas_interpret)
+            use_pallas=False)
 
     # ---------------------------------------------------------- checkpoint
     def state_dict(self) -> Dict[str, Any]:

@@ -54,9 +54,9 @@ from repro.kernels.tpe_kde.tpe_kde import tpe_scores_pallas
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "batch_size", "d_true", "use_pallas", "interpret", "block_s"))
+    "batch_size", "d_true", "use_pallas", "block_s"))
 def fused_tpe_propose(X, y, C, meta, *, batch_size: int, d_true: int,
-                      use_pallas: bool = False, interpret: bool = True,
+                      use_pallas: bool = False,
                       block_s: int = 256):
     """One device program per ask: split -> l/g scoring -> ``lax.top_k``.
 
@@ -112,7 +112,7 @@ def fused_tpe_propose(X, y, C, meta, *, batch_size: int, d_true: int,
                       jnp.float32(0.0)])[None, :]
     if use_pallas:
         score = tpe_scores_pallas(C, X, a, wg, wb, scal, d_true=d_true,
-                                  block_s=block_s, interpret=interpret)
+                                  block_s=block_s)
     else:
         score = tpe_scores_ref(C, X, a, wg, wb, scal, d_true=d_true)
     score = jnp.where(jnp.arange(C.shape[0]) < n_cand, score, -jnp.inf)
@@ -121,9 +121,9 @@ def fused_tpe_propose(X, y, C, meta, *, batch_size: int, d_true: int,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "batch_size", "d_true", "use_pallas", "interpret", "block_s"))
+    "batch_size", "d_true", "use_pallas", "block_s"))
 def fused_tpe_propose_bank(X, y, C, meta, *, batch_size: int, d_true: int,
-                           use_pallas: bool = False, interpret: bool = True,
+                           use_pallas: bool = False,
                            block_s: int = 256):
     """``fused_tpe_propose`` vmapped over a leading study axis (the
     StudyBank ask path): X (B, na, dp), y (B, na), C (B, Sp, dp) and one
@@ -133,7 +133,7 @@ def fused_tpe_propose_bank(X, y, C, meta, *, batch_size: int, d_true: int,
     indices."""
     one = functools.partial(fused_tpe_propose, batch_size=batch_size,
                             d_true=d_true, use_pallas=use_pallas,
-                            interpret=interpret, block_s=block_s)
+                            block_s=block_s)
     return jax.vmap(one)(X, y, C, meta)
 
 
@@ -142,7 +142,7 @@ class TPEStrategy(BaseStrategy):
 
     def __init__(self, dim: int, domain_size: float, gamma: float = 0.25,
                  pending_penalty: bool = False, fit_steps: int = 40,
-                 use_pallas: bool = False, pallas_interpret: bool = True,
+                 use_pallas: bool = False,
                  refit_every: int = 8):
         # fit_steps/refit_every belong to the standard strategy-constructor
         # contract; TPE has no GP to apply them to, so they are accepted and
@@ -162,7 +162,6 @@ class TPEStrategy(BaseStrategy):
         self.gamma = float(gamma)
         self.pending_penalty = bool(pending_penalty)
         self.use_pallas = bool(use_pallas)
-        self.pallas_interpret = bool(pallas_interpret)
 
     # ------------------------------------------------------------ host oracle
     def _split_count(self, n: int) -> int:
@@ -267,7 +266,7 @@ class TPEStrategy(BaseStrategy):
         meta = np.array([n, n_pend, S, self.gamma], np.float32)
         picks = fused_tpe_propose(
             Xb, yb, Cb, meta, batch_size=batch_size, d_true=d,
-            use_pallas=self.use_pallas, interpret=self.pallas_interpret)
+            use_pallas=self.use_pallas)
         picks = jax.device_get(picks)  # one explicit exit sync
         return [int(i) for i in picks]
 

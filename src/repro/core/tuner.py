@@ -21,7 +21,6 @@ Config keys (mirroring Mango's ``conf_dict``):
   domain_size (None -> heuristic), mc_samples (None -> heuristic),
   seed (0), early_stopping (callable(results) -> bool),
   checkpoint_path (None), fit_steps (40), use_pallas (False),
-  pallas_interpret (True; set False on real TPU for the compiled kernel),
   refit_every (8; full GP hyperparameter re-tune every N new observations —
   in between, observations extend the Cholesky incrementally in O(n^2)),
   scheduler (None; any ``repro.scheduler`` Scheduler — then ``objective``
@@ -44,7 +43,7 @@ from repro.core.optimizer import AskTellOptimizer, Trial
 DEFAULTS = dict(batch_size=1, num_iteration=20, initial_random=2,
                 optimizer="bayesian", domain_size=None, mc_samples=None,
                 seed=0, early_stopping=None, checkpoint_path=None,
-                fit_steps=40, use_pallas=False, pallas_interpret=True,
+                fit_steps=40, use_pallas=False,
                 refit_every=8, scheduler=None, strategy_kwargs=None)
 
 
@@ -93,7 +92,6 @@ class Tuner:
                 mc_samples=self.conf["mc_samples"],
                 fit_steps=self.conf["fit_steps"],
                 use_pallas=self.conf["use_pallas"],
-                pallas_interpret=self.conf["pallas_interpret"],
                 refit_every=self.conf["refit_every"],
                 strategy_kwargs=self.conf["strategy_kwargs"])
         self.space = self.opt.space

@@ -14,10 +14,13 @@ TPU adaptation of the FlashAttention GPU algorithm:
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import interpret_mode
 
 NEG_INF = -1e30
 
@@ -66,7 +69,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, out_ref, m_ref, l_ref, acc_ref, *,
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
                                              "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128, interpret: Optional[bool] = None):
     """q (B, H, Sq, hd); k/v (B, KV, Sk, hd) -> (B, H, Sq, hd)."""
     B, H, Sq, hd = q.shape
     KV, Sk = k.shape[1], k.shape[2]
@@ -78,8 +81,6 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
     scale = hd ** -0.5
 
     from jax.experimental.pallas import tpu as pltpu
-
-    from repro.compat import tpu_compiler_params
 
     kernel = functools.partial(_flash_kernel, causal=causal, scale=scale,
                                block_q=block_q, block_k=block_k, n_k=n_k)
@@ -105,8 +106,8 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
             pltpu.VMEM((block_q, 1), jnp.float32),   # l
             pltpu.VMEM((block_q, hd), jnp.float32),  # acc
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(qr, kr, vr)
     return out.reshape(B, H, Sq, hd)

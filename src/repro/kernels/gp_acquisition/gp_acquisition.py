@@ -23,10 +23,13 @@ acquisition`` on top of its (mu, sig2) output.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import interpret_mode
 
 
 def _score_cov_kernel(c_ref, x_ref, mask_ref, linvt_ref, alpha_ref, scal_ref,
@@ -69,7 +72,7 @@ def _score_cov_kernel(c_ref, x_ref, mask_ref, linvt_ref, alpha_ref, scal_ref,
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def score_cov_pallas(cands, X, mask, Linv, alpha, var, noise,
-                     block_s: int = 256, interpret: bool = True):
+                     block_s: int = 256, interpret: Optional[bool] = None):
     """(mu, sig2, cross-covariance block) for cands (S, d) pre-scaled.
 
     ``Linv`` is the triangular inverse factor L^{-1} (the shared scoring
@@ -102,7 +105,7 @@ def score_cov_pallas(cands, X, mask, Linv, alpha, var, noise,
             jax.ShapeDtypeStruct((S, 1), jnp.float32),
             jax.ShapeDtypeStruct((S, n), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(cands.astype(jnp.float32), X.astype(jnp.float32),
       mask[None, :].astype(jnp.float32), Linv.T.astype(jnp.float32),
       alpha[None, :].astype(jnp.float32), scal.astype(jnp.float32))
@@ -143,7 +146,7 @@ def _downdate_kernel(c_ref, xs_ref, kc_ref, u_ref, sig2_ref, scal_ref,
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def var_downdate_pallas(cands, x_star, Kc, u, schur, sig2, var,
-                        block_s: int = 256, interpret: bool = True):
+                        block_s: int = 256, interpret: Optional[bool] = None):
     """Apply the rank-1 downdate; returns (sig2', k(C, x*)).
 
     cands (S, d) and x_star (d,) pre-scaled by lengthscale; Kc (S, n) the
@@ -173,7 +176,7 @@ def var_downdate_pallas(cands, x_star, Kc, u, schur, sig2, var,
             jax.ShapeDtypeStruct((S, 1), jnp.float32),
             jax.ShapeDtypeStruct((S, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(cands.astype(jnp.float32), x_star[None, :].astype(jnp.float32),
       Kc.astype(jnp.float32), u[None, :].astype(jnp.float32),
       sig2[:, None].astype(jnp.float32), scal.astype(jnp.float32))

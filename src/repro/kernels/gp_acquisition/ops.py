@@ -2,8 +2,8 @@
 
 ``gp_mean_std`` adapts a ``repro.core.gp.GPState`` to the fused kernel so the
 batch strategies can use it via ``Tuner(config={"use_pallas": True})``.
-On CPU the kernel runs in interpret mode (correctness path); on TPU set
-``interpret=False`` for the compiled kernel.
+The kernel is compiled on a TPU and interpreted on the CPU
+(``repro.kernels.interpret_mode``).
 """
 from __future__ import annotations
 
@@ -34,7 +34,7 @@ def _prescale(cands, X, ls, block_s):
 
 
 def score_cov(cands, X, mask, Linv, alpha, ls, var, noise, *,
-              block_s: int = 256, interpret: bool = True):
+              block_s: int = 256):
     """(mu, sig2) for every candidate in ONE kernel dispatch (the cached
     cross-covariance block the kernel also emits is dropped here).
     ``Linv`` is the triangular inverse factor L^{-1}."""
@@ -43,12 +43,12 @@ def score_cov(cands, X, mask, Linv, alpha, ls, var, noise, *,
         jnp.asarray(c), jnp.asarray(Xp), jnp.asarray(mask, jnp.float32),
         jnp.asarray(Linv, jnp.float32), jnp.asarray(alpha, jnp.float32),
         jnp.asarray(var, jnp.float32), jnp.asarray(noise, jnp.float32),
-        block_s=block_s, interpret=interpret)
+        block_s=block_s)
     mu, sig2 = jax.device_get((mu, sig2))  # one explicit adapter exit
     return mu[:S], sig2[:S]
 
 
-def gp_mean_std(st, cands, interpret: bool = True):
+def gp_mean_std(st, cands):
     """GPState-facing adapter returning (mu, sd) in the original y scale."""
     if getattr(st, "Linv", None) is not None:
         # incrementally-maintained factor (track_factor): no O(n^3) solve
@@ -65,5 +65,5 @@ def gp_mean_std(st, cands, interpret: bool = True):
     # one scoring-kernel dispatch yields both moments (the old path ran
     # the UCB kernel twice, with beta=0 and beta=1, to recover sd)
     mu, sig2 = score_cov(cands, st.X, st.mask, Linv, alpha,
-                         np.asarray(st.ls), var, noise, interpret=interpret)
+                         np.asarray(st.ls), var, noise)
     return mu * st.y_std + st.y_mean, np.sqrt(sig2) * st.y_std

@@ -11,13 +11,14 @@ form so exp() never overflows.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
+from repro.kernels import interpret_mode
 
 NEG = -1e30
 
@@ -76,7 +77,7 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, li_ref, lf_ref, out_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def mlstm_chunk(q, k, v, logi, logf, *, chunk: int = 64,
-                interpret: bool = True):
+                interpret: Optional[bool] = None):
     """q/k/v (B, NH, S, dh); logi/logf (B, NH, S) -> h (B, NH, S, dh)."""
     B, NH, S, dh = q.shape
     L = min(chunk, S)
@@ -104,8 +105,8 @@ def mlstm_chunk(q, k, v, logi, logf, *, chunk: int = 64,
             pltpu.VMEM((1, dh), jnp.float32),   # n
             pltpu.VMEM((1, 1), jnp.float32),    # m
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(qr, kr, vr, lir, lfr)
     return out.reshape(B, NH, S, dh)

@@ -5,9 +5,8 @@ from repro.kernels.mlstm_chunk.mlstm_chunk import mlstm_chunk
 from repro.kernels.mlstm_chunk.ref import mlstm_ref
 
 
-def mlstm_mixer(q, k, v, logi, logf, *, use_pallas=True, interpret=True,
+def mlstm_mixer(q, k, v, logi, logf, *, use_pallas=True,
                 chunk=64):
     if use_pallas:
-        return mlstm_chunk(q, k, v, logi, logf, chunk=chunk,
-                           interpret=interpret)
+        return mlstm_chunk(q, k, v, logi, logf, chunk=chunk)
     return mlstm_ref(q, k, v, logi, logf)

@@ -18,13 +18,14 @@ intermediates in HBM.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
+from repro.kernels import interpret_mode
 
 
 def _ssm_kernel(a_ref, b_ref, c_ref, out_ref, h_ref, *, ck: int):
@@ -48,7 +49,7 @@ def _ssm_kernel(a_ref, b_ref, c_ref, out_ref, h_ref, *, ck: int):
 
 @functools.partial(jax.jit, static_argnames=("block_d", "chunk", "interpret"))
 def ssm_scan(Abar, Bx, Cc, *, block_d: int = 512, chunk: int = 64,
-             interpret: bool = True):
+             interpret: Optional[bool] = None):
     """Abar/Bx (B, S, di, N) fp32; Cc (B, S, N) -> y (B, S, di) fp32."""
     B, S, di, N = Abar.shape
     block_d = min(block_d, di)
@@ -71,8 +72,8 @@ def ssm_scan(Abar, Bx, Cc, *, block_d: int = 512, chunk: int = 64,
                                lambda b, d, s: (b, s, d)),
         out_shape=jax.ShapeDtypeStruct((B, S, di), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_d, N), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(Abar, Bx, Cc[:, :, None, :])
     return out

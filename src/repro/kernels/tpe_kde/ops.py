@@ -1,8 +1,8 @@
 """Host-facing wrapper: pads shapes to kernel-friendly sizes and dispatches.
 
 ``parzen_logdens`` scores unpadded numpy inputs through the Pallas kernel
-(interpret mode on CPU — the correctness path; set ``interpret=False`` on
-real TPU), matching ``TPEStrategy``'s numpy ``_log_kde`` oracle.  The fused
+(compiled on a TPU, interpreted on the CPU: ``repro.kernels.interpret_mode``),
+matching ``TPEStrategy``'s numpy ``_log_kde`` oracle.  The fused
 proposal program (``repro.core.tpe.fused_tpe_propose``) calls the raw
 kernels directly with pre-padded buffers, like ``gp.fused_propose_pallas``
 does for the ``gp_acquisition`` suite.
@@ -28,8 +28,7 @@ def pad_rows(n: int, multiple: int) -> int:
     return max(multiple, int(math.ceil(n / multiple)) * multiple)
 
 
-def parzen_logdens(cands, pts, *, bw=None, block_s: int = 256,
-                   interpret: bool = True):
+def parzen_logdens(cands, pts, *, bw=None, block_s: int = 256):
     """(m,) product-Parzen log-density of cands (m, d) under pts (n, d).
 
     ``bw`` defaults to the Scott-rule bandwidth the TPE strategy uses
@@ -56,5 +55,5 @@ def parzen_logdens(cands, pts, *, bw=None, block_s: int = 256,
     scal = np.array([[inv2bw2, 1.0 / max(n, 1), 0.0, 0.0]], np.float32)
     out = parzen_logdens_pallas(
         jnp.asarray(cb), jnp.asarray(xb), jnp.asarray(w),
-        jnp.asarray(scal), d_true=d, block_s=block_s, interpret=interpret)
+        jnp.asarray(scal), d_true=d, block_s=block_s)
     return jax.device_get(out)[:m]

@@ -23,10 +23,13 @@ argument); padded observation rows carry mask 0 in both splits.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import interpret_mode
 
 
 def _tpe_score_kernel(c_ref, x_ref, a_ref, wg_ref, wb_ref, scal_ref,
@@ -60,7 +63,7 @@ def _tpe_score_kernel(c_ref, x_ref, a_ref, wg_ref, wb_ref, scal_ref,
 @functools.partial(jax.jit,
                    static_argnames=("d_true", "block_s", "interpret"))
 def tpe_scores_pallas(cands, pts, a, wg, wb, scal, *, d_true: int,
-                      block_s: int = 256, interpret: bool = True):
+                      block_s: int = 256, interpret: Optional[bool] = None):
     """cands (S, dp) with S a block multiple; pts (n, dp); a (n, dp)
     per-row per-dim bandwidth scale; wg/wb (n,); scal (1, 4).  Returns
     the (S,) l/g log-ratio scores."""
@@ -80,7 +83,7 @@ def tpe_scores_pallas(cands, pts, a, wg, wb, scal, *, d_true: int,
         ],
         out_specs=pl.BlockSpec((block_s, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((S, 1), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(cands.astype(jnp.float32), pts.astype(jnp.float32),
       a.astype(jnp.float32), wg[None, :].astype(jnp.float32),
       wb[None, :].astype(jnp.float32), scal.astype(jnp.float32))
@@ -106,7 +109,7 @@ def _parzen_kernel(c_ref, x_ref, w_ref, scal_ref, out_ref, *, d_true: int):
 @functools.partial(jax.jit,
                    static_argnames=("d_true", "block_s", "interpret"))
 def parzen_logdens_pallas(cands, pts, w, scal, *, d_true: int,
-                          block_s: int = 256, interpret: bool = True):
+                          block_s: int = 256, interpret: Optional[bool] = None):
     """(S,) product-Parzen log-density of each candidate under (pts, w)."""
     S, dp = cands.shape
     n = pts.shape[0]
@@ -122,7 +125,7 @@ def parzen_logdens_pallas(cands, pts, w, scal, *, d_true: int,
         ],
         out_specs=pl.BlockSpec((block_s, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((S, 1), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(cands.astype(jnp.float32), pts.astype(jnp.float32),
       w[None, :].astype(jnp.float32), scal.astype(jnp.float32))
     return out[:, 0]

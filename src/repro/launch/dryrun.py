@@ -22,7 +22,6 @@ import time
 import traceback
 from pathlib import Path
 
-import repro.compat  # noqa: F401  (pins JAX_PLATFORMS=cpu on bare runners)
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P

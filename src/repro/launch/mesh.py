@@ -13,20 +13,24 @@ from typing import Optional, Tuple
 
 import jax
 
-from repro.compat import make_mesh
 from repro.models.common import ShardCtx
+
+
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(shape: Tuple[int, ...] = (2, 2),
                    axes: Tuple[str, ...] = ("data", "model")):
     """Small mesh for CI-scale sharding tests (run under forced host devices)."""
-    return make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_shard_ctx(mesh: Optional[jax.sharding.Mesh],

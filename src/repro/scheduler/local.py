@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import concurrent.futures as cf
 import logging
+import multiprocessing
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -104,7 +105,15 @@ class ThreadScheduler(BatchSchedulerBase):
 
 
 class ProcessScheduler(BatchSchedulerBase):
-    """Process-pool evaluation (trial_fn must be picklable)."""
+    """Process-pool evaluation (trial_fn must be picklable).
+
+    Workers are started with ``spawn``, never ``fork``: a fork of a
+    process that has initialised JAX copies its runtime threads and device
+    handles into the child.  A trial in a child process cannot use the
+    chip either way: the tuner's process holds it, and a chip belongs to
+    one process at a time.  Objectives that run on the chip use
+    ``ThreadScheduler`` or ``SerialScheduler`` in the tuner's process.
+    """
 
     def __init__(self, n_workers: int = 2, timeout: Optional[float] = None):
         self.n_workers = n_workers
@@ -113,7 +122,9 @@ class ProcessScheduler(BatchSchedulerBase):
     def make_objective(self, trial_fn: TrialFn) -> Objective:
         def objective(params_list):
             evals, params = [], []
-            with cf.ProcessPoolExecutor(max_workers=self.n_workers) as ex:
+            with cf.ProcessPoolExecutor(
+                    max_workers=self.n_workers,
+                    mp_context=multiprocessing.get_context("spawn")) as ex:
                 futs = {ex.submit(trial_fn, par): par for par in params_list}
                 try:
                     for fut in cf.as_completed(futs, timeout=self.timeout):

@@ -12,9 +12,10 @@ verbatim* — same ``req_id``, same trial id — exercising every recovery
 guarantee at once: torn-tail truncation, WAL suffix replay over the
 snapshot, ask dedup, tell dedup.
 
-After the workload (plus one final crash-free restart, proving recovery
-is idempotent), an uninterrupted in-process oracle runs the identical
-script in a second data dir, and the harness asserts:
+After the workload, one final crash-free restart (proving recovery is
+idempotent) answers the comparison's reads and is stopped.  Then an
+uninterrupted oracle service, with no crash points, runs the identical
+script in a second data dir over HTTP, and the harness asserts:
 
   * ``op_seq`` equal — no journaled op was lost or double-counted;
   * every study's full trial ledger (ids, params, status, values) is
@@ -22,6 +23,11 @@ script in a second data dir, and the harness asserts:
   * the *next* proposals from both services are bit-equal — the
     recovered optimizer state (RNG streams, GP fit schedule) is exact,
     not merely consistent.
+
+Only one service process runs at a time, and the harness itself never
+imports JAX: on an accelerator the service holds the chip, which belongs
+to one process at a time.  Each service inherits the harness's
+environment unchanged.
 
 Exit code 0 = all phases passed; on failure the data dirs (WAL +
 snapshots) are left in place as artifacts.
@@ -150,7 +156,6 @@ class ServerProc:
     def __init__(self, data_dir: str, config_path: Optional[str],
                  crash_spec: str = ""):
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         if crash_spec:
             env["REPRO_SERVICE_CRASH"] = crash_spec
         else:
@@ -193,23 +198,41 @@ class ServerProc:
             self.proc.wait()
 
 
-# ----------------------------------------------------------------- oracle
-class OracleExec:
-    """In-process uninterrupted run of the same workload (the ground
-    truth the chaos run must be bit-equal to)."""
+# ---------------------------------------------------------------- compare
+def collect(client: ServiceClient, names: List[str],
+            batch: int) -> Dict[str, Any]:
+    """What the comparison reads from one service, over HTTP: its op_seq,
+    every study's trial ledger, and the *next* ``2 * batch`` proposals of
+    each study (asked in name order, so both services see the same op
+    stream)."""
+    return {"op_seq": client.health()["op_seq"],
+            "trials": {n: client.trials(n)["trials"] for n in names},
+            "next": {n: client.ask(n, n=2 * batch)["trials"]
+                     for n in names}}
 
-    def __init__(self, data_dir: str, config: Dict[str, Any]):
-        from repro.service.server import CrashPoints, TuningService
-        # explicit empty spec: the oracle must never inherit the harness
-        # environment's crash points
-        self.svc = TuningService(data_dir, config=config,
-                                 crash=CrashPoints(""))
 
-    def __getattr__(self, item):
-        if item in ("create_study", "ask", "tell", "tell_failed", "trace",
-                    "compact", "best", "results", "trials", "health"):
-            return getattr(self.svc, item)
-        raise AttributeError(item)
+def compare(svc: Dict[str, Any], orc: Dict[str, Any]) -> List[str]:
+    failures: List[str] = []
+    if svc["op_seq"] != orc["op_seq"]:
+        failures.append(f"op_seq diverged: service {svc['op_seq']} "
+                        f"vs oracle {orc['op_seq']}")
+    for name in orc["trials"]:
+        t_svc, t_orc = svc["trials"][name], orc["trials"][name]
+        if t_svc != t_orc:
+            failures.append(f"{name}: trial ledger diverged "
+                            f"(dedup violated or replay drifted)")
+            for a, b in zip(t_svc, t_orc):
+                if a != b:
+                    failures.append(f"  first diff: {a!r} != {b!r}")
+                    break
+        # remaining proposals must be bit-equal: the recovered RNG/GP
+        # state, not just the ledger, is exact
+        p_svc, p_orc = svc["next"][name], orc["next"][name]
+        if p_svc != p_orc:
+            failures.append(f"{name}: post-recovery proposals diverged")
+            failures.append(f"  service: {p_svc!r}")
+            failures.append(f"  oracle:  {p_orc!r}")
+    return failures
 
 
 # ------------------------------------------------------------------ main
@@ -224,6 +247,7 @@ def run(data_dir: str, kills: int = 5, seed: int = 0, studies: int = 3,
     oracle_dir = os.path.join(data_dir, "oracle")
     cfg_path = os.path.join(data_dir, "config.json")
     atomic_write_text(cfg_path, json.dumps(cfg))
+    names = [f"s{i}" for i in range(studies)]
 
     def say(msg):
         if verbose:
@@ -266,43 +290,27 @@ def run(data_dir: str, kills: int = 5, seed: int = 0, studies: int = 3,
     server.kill()
 
     # final crash-free restart: recovery must be idempotent (replaying an
-    # already-recovered dir changes nothing)
+    # already-recovered dir changes nothing).  One service runs at a time
+    # and this process never touches JAX: on an accelerator the service
+    # holds the chip, which belongs to one process at a time.
     server = ServerProc(svc_dir, None, "")
-    client = ServiceClient(server.base_url, timeout=60.0, retries=2)
+    try:
+        got = collect(ServiceClient(server.base_url, timeout=60.0,
+                                    retries=2), names, batch)
+    finally:
+        server.kill()
 
     say("chaos: running uninterrupted oracle")
-    oracle = OracleExec(oracle_dir, cfg)
-    for step in list(Workload(seed, studies, rounds, batch).steps()):
-        exec_step(oracle, step)
+    server = ServerProc(oracle_dir, cfg_path, "")
+    try:
+        oracle = ServiceClient(server.base_url, timeout=60.0, retries=2)
+        for step in Workload(seed, studies, rounds, batch).steps():
+            exec_step(oracle, step)
+        want = collect(oracle, names, batch)
+    finally:
+        server.kill()
 
-    # ---------------------------------------------------------- compare
-    failures: List[str] = []
-    h_svc, h_orc = client.health(), oracle.health()
-    if h_svc["op_seq"] != h_orc["op_seq"]:
-        failures.append(f"op_seq diverged: service {h_svc['op_seq']} "
-                        f"vs oracle {h_orc['op_seq']}")
-    names = [f"s{i}" for i in range(studies)]
-    for name in names:
-        t_svc = client.trials(name)["trials"]
-        t_orc = oracle.trials(name)["trials"]
-        if t_svc != t_orc:
-            failures.append(f"{name}: trial ledger diverged "
-                            f"(dedup violated or replay drifted)")
-            for a, b in zip(t_svc, t_orc):
-                if a != b:
-                    failures.append(f"  first diff: {a!r} != {b!r}")
-                    break
-        # remaining proposals must be bit-equal: the recovered RNG/GP
-        # state, not just the ledger, is exact
-        p_svc = client.ask(name, n=2 * batch)["trials"]
-        p_orc = oracle.ask(name, n=2 * batch)["trials"]
-        if p_svc != p_orc:
-            failures.append(f"{name}: post-recovery proposals diverged")
-            failures.append(f"  service: {p_svc!r}")
-            failures.append(f"  oracle:  {p_orc!r}")
-    server.kill()
-    oracle.svc.close()
-
+    failures = compare(got, want)
     report = {"kills_requested": kills, "kills_fired": len(fired),
               "fired": fired, "steps": len(steps), "failures": failures}
     say(f"chaos: {len(fired)}/{kills} kills fired over {len(steps)} steps; "

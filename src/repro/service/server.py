@@ -600,6 +600,8 @@ def main(argv=None) -> int:
     ap.add_argument("--config", default=None,
                     help="JSON config file (first start only)")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     config = None
     if args.config:
         with open(args.config) as fh:

@@ -251,3 +251,18 @@ def test_tpe_score_kernel_matches_ref(S, n, d_true):
                          jnp.asarray(a), jnp.asarray(wg),
                          jnp.asarray(wb), jnp.asarray(scal), d_true=d_true)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)
+
+
+# --------------------------------------------------------------------------- #
+# interpret mode follows the backend
+# --------------------------------------------------------------------------- #
+def test_interpret_mode_is_on_exactly_on_the_cpu(monkeypatch):
+    from repro.kernels import interpret_mode
+    assert jax.default_backend() == "cpu"
+    assert interpret_mode() is True
+    assert interpret_mode(True) is True
+    assert interpret_mode(False) is False   # compile for a described TPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert interpret_mode() is False
+    with pytest.raises(ValueError, match="CPU backend"):
+        interpret_mode(True)

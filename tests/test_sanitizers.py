@@ -88,6 +88,35 @@ def test_no_transfer_implicit_h2d_raises_explicit_allowed():
             jnp.sin(x)  # implicit operand upload
 
 
+def test_no_transfer_all_threads_guards_other_threads():
+    """A server's handler threads run the device work: the thread-local
+    guard misses them, the process-wide one does not, and the previous
+    level comes back after the block."""
+    x = np.ones(3, np.float32)
+    errors = []
+
+    def work():
+        try:
+            jnp.sin(x)  # implicit operand upload
+        except Exception as e:  # noqa: BLE001
+            errors.append(e)
+
+    def in_thread():
+        t = threading.Thread(target=work)
+        t.start()
+        t.join()
+
+    with no_transfer(device_to_host=None, host_to_device="disallow"):
+        in_thread()
+    assert errors == []
+    with no_transfer(device_to_host=None, host_to_device="disallow",
+                     all_threads=True):
+        in_thread()
+    assert len(errors) == 1 and "isallow" in str(errors[0])
+    in_thread()
+    assert len(errors) == 1
+
+
 def test_no_transfer_default_keeps_device_get_and_uploads_open():
     y = jax.jit(lambda v: v + 1)(jnp.ones(3))
     jax.block_until_ready(y)
@@ -220,6 +249,8 @@ def _load_multi_study():
 
 def test_multi_study_main_exits_nonzero_on_retraces(monkeypatch):
     mod = _load_multi_study()
+    import repro.compile_cache
+    monkeypatch.setattr(repro.compile_cache, "enable_compile_cache", lambda: "")
     monkeypatch.setattr(mod, "run_throughput", lambda **kw: [])
     monkeypatch.setattr(mod, "run_retrace_sweep", lambda **kw: 3)
     monkeypatch.setattr(sys, "argv", ["multi_study.py", "--quick"])

@@ -36,6 +36,29 @@ def test_serial_scheduler_drops_failures():
     assert all(p["x"] <= 0.8 for p in params)
 
 
+def test_process_scheduler_spawns_workers(monkeypatch):
+    """Workers start with ``spawn``: a forked child of a process that has
+    initialised JAX would inherit its runtime and device handles."""
+    import concurrent.futures as cf
+
+    from repro.scheduler import ProcessScheduler
+
+    methods = []
+    real = cf.ProcessPoolExecutor
+
+    def recording(*a, **kw):
+        methods.append(kw["mp_context"].get_start_method())
+        return real(*a, **kw)
+
+    monkeypatch.setattr(cf, "ProcessPoolExecutor", recording)
+    # ``len`` pickles by reference and needs no import in the child
+    obj = ProcessScheduler(n_workers=2, timeout=60).make_objective(len)
+    evals, params = obj([{"x": 0.1}, {"x": 0.2, "y": 1.0}])
+    assert methods == ["spawn"]
+    assert sorted(evals) == [1.0, 2.0]
+    assert len(params) == 2
+
+
 def test_thread_scheduler_straggler_deadline():
     def slow(p):
         if p["x"] > 0.5:

@@ -529,3 +529,61 @@ def test_chaos_kill_restart_quick(tmp_path):
                        studies=2, rounds=3, verbose=False)
     assert report["failures"] == []
     assert report["kills_fired"] == 2
+
+
+def test_chaos_harness_never_imports_jax():
+    """The harness drives every service over HTTP and never touches JAX
+    itself: on an accelerator the service it starts holds the chip."""
+    import subprocess
+    import sys
+    code = ("import sys; import repro.service.chaos; "
+            "print('jax' in sys.modules)")
+    out = subprocess.run([sys.executable, "-c", code], check=True,
+                         capture_output=True, text=True,
+                         env={**os.environ, "PYTHONPATH": os.path.join(
+                             os.path.dirname(__file__), "..", "src")})
+    assert out.stdout.strip() == "False"
+
+
+# --------------------------------------------------------------------------- #
+# chip_smoke.py: the one-chip smoke of this path, rehearsed small on the CPU
+# --------------------------------------------------------------------------- #
+def _chip_smoke():
+    import importlib.util
+    from pathlib import Path
+    path = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("_chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_chip_smoke_device_check_refuses_the_cpu():
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        _chip_smoke().require_tpu()
+
+
+def test_chip_smoke_exits_nonzero_without_a_tpu():
+    import subprocess
+    import sys
+    from pathlib import Path
+    script = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    out = subprocess.run([sys.executable, str(script)], capture_output=True,
+                         text=True, timeout=300,
+                         env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert out.returncode != 0
+    assert '"ok": true' not in out.stdout
+
+
+def test_chip_smoke_phases_pass_small_on_cpu(tmp_path, monkeypatch):
+    """Every phase of the smoke at a toy fleet: HTTP replies, the float64
+    reference, WAL-replay bit-equality, zero steady-state compiles."""
+    import repro.compile_cache
+    monkeypatch.setattr(repro.compile_cache, "enable_compile_cache",
+                        lambda: str(tmp_path / "cache"))
+    run = _chip_smoke().run(out_dir=tmp_path / "smoke", studies=3,
+                            seed_obs=40, rounds=2, mc_samples=64)
+    assert run.failures == []
+    assert run.facts["steady_compiles"] == 0
+    assert run.facts["restart_bit_equal"] == "3/3"
+    assert run.facts["http_replies_2xx"] > 0

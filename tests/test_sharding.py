@@ -96,7 +96,7 @@ def test_compressed_psum_shard_map():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.launch.mesh import make_test_mesh
         from repro.optim.compression import compressed_psum
         from jax.sharding import PartitionSpec as P
