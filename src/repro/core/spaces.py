@@ -44,6 +44,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro.tracing import span
+
 # key carrying the active branch name inside a sampled Choice value
 CHOICE_KEY = "_choice"
 # encoded value of an inactive conditional dim (center of the unit cube:
@@ -505,19 +507,24 @@ class ParamSpace:
     # only the few winning rows ever become config dicts (``config_at``).
     def sample_columns(self, n: int,
                        rng: np.random.Generator) -> Dict[str, Any]:
-        if self.constraints:
-            # constrained spaces route through the row sampler so columnar
-            # and scalar draws stay trivially the same stream (rejection
-            # makes the draw count data-dependent; no columnar shortcut)
-            rows = self.sample(n, rng)
-            return {p.name: [r[p.name] for r in rows] for p in self.params}
-        return {p.name: p.sample_array(n, rng) for p in self.params}
+        with span("mango.sample_columns"):
+            if self.constraints:
+                # constrained spaces route through the row sampler so
+                # columnar and scalar draws stay trivially the same stream
+                # (rejection makes the draw count data-dependent; no
+                # columnar shortcut)
+                rows = self.sample(n, rng)
+                return {p.name: [r[p.name] for r in rows]
+                        for p in self.params}
+            return {p.name: p.sample_array(n, rng) for p in self.params}
 
     def encode_columns(self, cols: Dict[str, List[Any]],
                        n: int) -> np.ndarray:
-        blocks = [p.encode(cols[p.name]) for p in self.params if p.dims]
-        return (np.concatenate(blocks, axis=1) if blocks
-                else np.zeros((n, 0)))
+        with span("mango.encode_columns"):
+            blocks = [p.encode(cols[p.name]) for p in self.params
+                      if p.dims]
+            return (np.concatenate(blocks, axis=1) if blocks
+                    else np.zeros((n, 0)))
 
     def config_at(self, cols: Dict[str, Any], i: int) -> Dict:
         # .item() unwraps ndarray columns to Python scalars so trial params

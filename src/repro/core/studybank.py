@@ -48,11 +48,21 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.tracing import Counters, span
+
 # trial-status codes (ledger ``status`` array; 0 = empty slot)
 S_EMPTY, S_PENDING, S_OBSERVED, S_FAILED = 0, 1, 2, 3
 
 _U64 = np.uint64
 _MASK64 = (1 << 64) - 1
+
+
+# what the bank counts for operators (``TuningService.stats``): the obs
+# stage's calls and cache hits, the fits with the rows due and the rows
+# run, and the copy of the factors to the host
+BANK_COUNTERS = ("obs_stage.calls", "obs_stage.hits", "obs_stage.ns",
+                 "fit.calls", "fit.rows_due", "fit.rows_run", "fit.ns",
+                 "factors_copy.ns", "factors_copy.bytes")
 
 
 def _pow2(n: int) -> int:
@@ -323,6 +333,7 @@ class StudyBank:
         self.seed = seed
         self.ledger = StudyLedger(n_studies, self.space.dim)
         self._gp_cache = None   # obs_stamp-keyed device state (staged ask)
+        self.counters = Counters(BANK_COUNTERS)
         # monotonic operation sequence for journaled (WAL) deployments: the
         # last op applied through ``apply_op``; snapshots carry it so crash
         # recovery can skip journal records the snapshot already contains
@@ -363,6 +374,7 @@ class StudyBank:
         bank.seed = None
         bank.ledger = view._led
         bank._gp_cache = None
+        bank.counters = Counters(BANK_COUNTERS)
         bank.op_seq = 0
         bank.extra = None
         bank._rng = None
@@ -608,12 +620,11 @@ class StudyBank:
             if f == "tpe":
                 Xd, yraw, _ = self._gather_obs(k_obs[rows], na, rows)
                 Pd = self._gather_pend(k_pend[rows], pend_cap, rows)
-                idx = self._dispatch_tpe(Xd, yraw, Pd, C[rows],
-                                         k_obs[rows], k_pend[rows], n, na)
+                idx = self._pick_tpe(Xd, yraw, Pd, C[rows], k_obs[rows],
+                                     k_pend[rows], n, na)
             else:
                 idx = self._pick_gp(cache, rows, f, C[rows], k_obs[rows],
                                     k_pend[rows], n, na, pend_cap)
-            idx = np.asarray(jax.device_get(idx))   # one exit sync / family
             flat = (rows[:, None] * n_mc + idx).astype(np.int64)  # (R, n)
             cfgs = space.configs_at(cols, flat.ravel())
             enc = Cflat[flat.ravel()].reshape(len(rows), -1, Cflat.shape[1])
@@ -642,13 +653,13 @@ class StudyBank:
         if fam == "tpe":
             Xd, yraw, _ = self._gather_obs(k_obs[rows], na, rows)
             Pd = self._gather_pend(k_pend[rows], pend_cap, rows)
-            idx = self._dispatch_tpe(Xd, yraw, Pd, C, k_obs[rows],
-                                     k_pend[rows], n, na)
+            idx = self._pick_tpe(Xd, yraw, Pd, C, k_obs[rows],
+                                 k_pend[rows], n, na)
         else:
             cache = self._obs_stage(k_obs, na)
             idx = self._pick_gp(cache, rows, fam, C, k_obs[rows],
                                 k_pend[rows], n, na, pend_cap)
-        idx = np.asarray(jax.device_get(idx))[0].astype(np.int64)
+        idx = idx[0].astype(np.int64)
         return space.configs_at(cols, idx), Cflat[idx]
 
     def _gather_obs(self, k_obs: np.ndarray, na: int, rows: np.ndarray):
@@ -737,11 +748,19 @@ class StudyBank:
         sel = np.nonzero(due)[0]
         for i in sel:
             ym[i], ys[i] = _y_standardization(yraw[i, :int(ko64[i])])
-        lls, lv, ln = gp_lib.fit_hypers_bank(
-            Xd, yraw, mask, led.log_ls[rows], led.log_var[rows],
-            led.log_noise[rows], ym, ys, steps=self.fit_steps)
-        # one explicit exit transfer for the three hyper arrays
-        lls, lv, ln = jax.device_get((lls, lv, ln))
+        # the fit runs every row of the sub-batch; only the due rows
+        # keep its result
+        c = self.counters
+        c.add("fit.calls")
+        c.add("fit.rows_due", len(sel))
+        c.add("fit.rows_run", len(rows))
+        with span("mango.fit", rows_due=len(sel), rows_run=len(rows)), \
+                c.timed("fit.ns"):
+            lls, lv, ln = gp_lib.fit_hypers_bank(
+                Xd, yraw, mask, led.log_ls[rows], led.log_var[rows],
+                led.log_noise[rows], ym, ys, steps=self.fit_steps)
+            # one explicit exit transfer for the three hyper arrays
+            lls, lv, ln = jax.device_get((lls, lv, ln))
         g = np.asarray(rows)[sel]
         led.log_ls[g] = lls[sel]
         led.log_var[g] = lv[sel]
@@ -761,31 +780,48 @@ class StudyBank:
         ask/tell_failed steady state pays only the candidate-dependent
         pick stages."""
         led = self.ledger
-        gpr = self._gp_fam_rows
-        ko = k_obs[gpr]
-        signs = tuple(self._members[int(b)].sign for b in gpr)
+        signs = tuple(self._members[int(b)].sign for b in self._gp_fam_rows)
         key = (led.obs_stamp, na, signs)
         cache = self._gp_cache
-        if cache is not None and cache["key"] == key:
-            return cache
+        hit = cache is not None and cache["key"] == key
+        self.counters.add("obs_stage.calls")
+        self.counters.add("obs_stage.hits", int(hit))
+        with span("mango.obs_stage", hit=int(hit)), \
+                self.counters.timed("obs_stage.ns"):
+            return cache if hit else self._factor_obs(k_obs, na, signs)
+
+    def _factor_obs(self, k_obs: np.ndarray, na: int, signs: tuple):
+        """The obs stage's cache miss: gather, fit if due, factor, and
+        copy the factors to the ledger."""
+        led = self.ledger
+        gpr = self._gp_fam_rows
+        ko = k_obs[gpr]
         from repro.core import gp as gp_lib
-        Xd, yraw, mask = self._gather_obs(ko, na, gpr)
-        if self._fit_if_due(Xd, yraw, mask, ko, gpr):
-            key = (led.obs_stamp, na, signs)
+        with span("mango.gather"):
+            Xd, yraw, mask = self._gather_obs(ko, na, gpr)
+        self._fit_if_due(Xd, yraw, mask, ko, gpr)   # a refit bumps the stamp
         # frozen standardization, exactly the single-study GP contract
         z = (yraw - led.y_mean[gpr][:, None]) / led.y_std[gpr][:, None]
         z = (z * mask).astype(np.float32)
         ls = np.exp(led.log_ls[gpr]).astype(np.float32)
         var = np.exp(led.log_var[gpr]).astype(np.float32)
         noise = (np.exp(led.log_noise[gpr]) + 1e-5).astype(np.float32)
-        L, Linv, cond = gp_lib.bank_factors(Xd, mask, ls, var, noise)
-        Xs = gp_lib.bank_prescale_X(Xd, ls)
+        with span("mango.factors"):
+            L, Linv, cond = gp_lib.bank_factors(Xd, mask, ls, var, noise)
+            Xs = gp_lib.bank_prescale_X(Xd, ls)
+            # the copy below waits for the factors anyway: waiting here
+            # keeps the copy's time the transfer's
+            jax.block_until_ready((L, Linv, cond))
         led.ensure_gp_capacity(na)
-        L_host, Linv_host, cond_host = jax.device_get((L, Linv, cond))
-        led.L[gpr, :na, :na] = L_host
-        led.Linv[gpr, :na, :na] = Linv_host
+        nbytes = int(L.nbytes + Linv.nbytes + cond.nbytes)
+        self.counters.add("factors_copy.bytes", nbytes)
+        with span("mango.factors_copy", bytes=nbytes), \
+                self.counters.timed("factors_copy.ns"):
+            L_host, Linv_host, cond_host = jax.device_get((L, Linv, cond))
+            led.L[gpr, :na, :na] = L_host
+            led.Linv[gpr, :na, :na] = Linv_host
         cache = self._gp_cache = {
-            "key": key, "Xs": Xs, "z": jnp.asarray(z),
+            "key": (led.obs_stamp, na, signs), "Xs": Xs, "z": jnp.asarray(z),
             "mask": jnp.asarray(mask), "L": L, "Linv": Linv,
             "ls": jnp.asarray(ls), "var": jnp.asarray(var),
             "noise": jnp.asarray(noise),
@@ -810,6 +846,19 @@ class StudyBank:
                 " or fewer near-duplicate observations)", RuntimeWarning)
 
     def _pick_gp(self, cache, rows, fam, C, ko, kp, n, na, pend_cap):
+        """One GP-family sub-batch's picks ``(R, n)``, on the host (one
+        exit sync per family).  The span carries what the pick works on:
+        ``S`` candidates of ``d`` dims, the batch ``n``, and each study's
+        rows in the system (observations and pending), not the bucket's."""
+        with span("mango.pick_gp",
+                  rows=lambda: ",".join(str(int(a) + int(b))
+                                        for a, b in zip(ko, kp)),
+                  S=int(C.shape[1]), d=int(C.shape[2]), n=int(n)):
+            idx = self._dispatch_gp(cache, rows, fam, C, ko, kp, n, na,
+                                    pend_cap)
+            return np.asarray(jax.device_get(idx))
+
+    def _dispatch_gp(self, cache, rows, fam, C, ko, kp, n, na, pend_cap):
         """Candidate-dependent stages for one family sub-batch, sliced
         out of the shared obs-stage cache: prescale-C, pending absorb,
         distances, exp, and the family's pick head (GP-BUCB downdate loop
@@ -849,6 +898,12 @@ class StudyBank:
         return gp_lib.bank_pick(
             d2, s, e, Cs, z, maskd, L, Linv, var, noise, n_eff,
             dom, batch_size=n, S=C.shape[1])
+
+    def _pick_tpe(self, Xd, yraw, Pd, C, k_obs, k_pend, n, na):
+        """One TPE sub-batch's picks ``(R, n)``, on the host."""
+        with span("mango.pick_tpe"):
+            idx = self._dispatch_tpe(Xd, yraw, Pd, C, k_obs, k_pend, n, na)
+            return np.asarray(jax.device_get(idx))
 
     def _dispatch_tpe(self, Xd, yraw, Pd, C, k_obs, k_pend, n, na):
         from repro.core import tpe as tpe_lib

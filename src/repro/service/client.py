@@ -130,6 +130,9 @@ class ServiceClient:
     def health(self) -> Dict[str, Any]:
         return self._request("GET", "/health")
 
+    def stats(self) -> Dict[str, Any]:
+        return self._request("GET", "/stats")
+
     def compact(self) -> Dict[str, Any]:
         return self._request("POST", "/admin/compact", {})
 

@@ -38,10 +38,12 @@ chaos harness; unset in production.
 from __future__ import annotations
 
 import argparse
+import itertools
 import json
 import os
 import signal
 import threading
+import time
 from collections import OrderedDict
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Callable, Dict, List, Optional
@@ -51,8 +53,16 @@ from repro.analysis.sanitizers import assert_holds
 from repro.service.client import ServiceError
 from repro.service.recovery import CONFIG, SNAPSHOT, WAL_FILE, recover
 from repro.service.wal import WriteAheadLog, atomic_write_text
+from repro.tracing import Counters, span
 
 REPLY_CACHE_CAP = 128   # retained req_id replies per study
+SERVICE_COUNTERS = ("replies_cached", "lock.acquisitions", "lock.wait_ns",
+                    "lock.held_ns", "journal.appends", "journal.ns")
+# the request kinds ``requests.<verb>`` / ``failed.<verb>`` count; any
+# other path counts as ``other``, so clients cannot mint counter names
+VERBS = frozenset(("health", "stats", "studies", "create", "compact",
+                   "ask", "tell", "tell_failed", "observe", "trace",
+                   "best", "results", "trials"))
 
 
 def space_from_spec(spec: Dict[str, Any]) -> Dict[str, Any]:
@@ -145,6 +155,49 @@ class CrashPoints:
         return lambda: self.check(tag)
 
 
+class _TimedLock:
+    """The service's one re-entrant lock, counting what it costs: the
+    wait of each outermost acquisition (``lock.wait_ns``, the span
+    ``mango.lock_wait``) and the time from it to the matching release
+    (``lock.held_ns``), which is what queues everyone else."""
+
+    def __init__(self, counters: Counters):
+        self._lock = threading.RLock()
+        self._counters = counters
+        self._depth = 0      # the owner's nesting; only the owner touches it
+        self._t_held = 0
+
+    def acquire(self, blocking: bool = True, timeout: float = -1) -> bool:
+        if self._lock._is_owned():
+            self._depth += 1
+            return self._lock.acquire()
+        t = time.perf_counter_ns()
+        with span("mango.lock_wait"):
+            ok = self._lock.acquire(blocking, timeout)
+        if ok:
+            self._t_held = time.perf_counter_ns()
+            self._depth = 1
+            self._counters.add("lock.acquisitions")
+            self._counters.add("lock.wait_ns", self._t_held - t)
+        return ok
+
+    def release(self) -> None:
+        self._depth -= 1
+        if self._depth == 0:
+            self._counters.add("lock.held_ns",
+                               time.perf_counter_ns() - self._t_held)
+        self._lock.release()
+
+    def _is_owned(self) -> bool:
+        return self._lock._is_owned()
+
+    def __enter__(self) -> bool:
+        return self.acquire()
+
+    def __exit__(self, *exc) -> None:
+        self.release()
+
+
 class TuningService:
     """The service core: bank + WAL + side tables, HTTP-agnostic."""
 
@@ -175,7 +228,10 @@ class TuningService:
         self.compact_every_ops = int(cfg.get("compact_every_ops", 0))
         self.compact_interval_s = float(cfg.get("compact_interval_s", 0.0))
         self.crash = crash or CrashPoints()
-        self._lock = threading.RLock()
+        # served work only: never snapshotted, journaled or replayed
+        self.counters = Counters(SERVICE_COUNTERS)
+        self._request_numbers = itertools.count(1)
+        self._lock = _TimedLock(self.counters)
         self._names: Dict[str, int] = {}
         # per-study req_id -> trial-id list: asks cache their proposal ids,
         # observes the single registered id, traces an empty list (the
@@ -187,6 +243,7 @@ class TuningService:
         self.recovery = recover(
             self.data_dir, self.bank, self._apply_record,
             on_snapshot=lambda: self._restore_extra(self.bank.extra))
+        self.bank.counters.clear()    # the replay is not served work
         self.wal = WriteAheadLog(os.path.join(self.data_dir, WAL_FILE))
         # background compaction: the request path only *signals* (an Event
         # set is nanoseconds); the snapshot+truncate stall moves off the
@@ -255,19 +312,24 @@ class TuningService:
         can't apply may reach the log."""
         assert_holds(self._lock)
         op = dict(op)
-        self.bank.validate_op(op)
-        op["seq"] = self.bank.next_op_seq()
         kind = op["op"]
-        self.crash.check(f"{kind}.before_journal")
-        try:
-            self.wal.append(op, mid_hook=self.crash.hook(
-                f"{kind}.mid_journal"))
-        except OSError as e:
-            self.wal_error = f"{type(e).__name__}: {e}"
-            self._check_writable()
-        self.crash.check(f"{kind}.after_journal")
-        result = self._apply_record(op)
-        self.crash.check(f"{kind}.after_apply")
+        seq = self.bank.next_op_seq()
+        with span("mango.commit", op=kind, seq=seq):
+            self.bank.validate_op(op)
+            op["seq"] = seq
+            self.crash.check(f"{kind}.before_journal")
+            try:
+                with span("mango.journal"), \
+                        self.counters.timed("journal.ns"):
+                    self.counters.add("journal.appends")
+                    self.wal.append(op, mid_hook=self.crash.hook(
+                        f"{kind}.mid_journal"))
+            except OSError as e:
+                self.wal_error = f"{type(e).__name__}: {e}"
+                self._check_writable()
+            self.crash.check(f"{kind}.after_journal")
+            result = self._apply_record(op)
+            self.crash.check(f"{kind}.after_apply")
         self._ops_since_snapshot += 1
         if (self.compact_every_ops
                 and self._ops_since_snapshot >= self.compact_every_ops):
@@ -314,12 +376,13 @@ class TuningService:
 
     def ask(self, name: str, n: int = 1,
             req_id: Optional[str] = None) -> Dict[str, Any]:
-        with self._lock:
+        with span("mango.ask"), self._lock:
             b = self._row(name)
             view = self.bank.studies[b]
             if req_id is not None:
                 cached = self._reply_cache.get(b, {}).get(req_id)
                 if cached is not None:
+                    self.counters.add("replies_cached")
                     return {"trials": [self._trial_json(view._trials[i])
                                        for i in cached], "cached": True}
             self._check_writable()
@@ -336,7 +399,7 @@ class TuningService:
 
     def _resolve(self, name: str, trial_id: int, kind: str,
                  **extra) -> Dict[str, Any]:
-        with self._lock:
+        with span(f"mango.{kind}"), self._lock:
             b = self._row(name)
             view = self.bank.studies[b]
             t = view._trials.get(int(trial_id))
@@ -347,6 +410,7 @@ class TuningService:
             if t.status != PENDING:
                 # duplicate delivery: reply, don't journal — retries must
                 # not grow the WAL
+                self.counters.add("replies_cached")
                 return {**self._trial_json(t), "applied": False}
             self._check_writable()
             t, applied = self._commit({"op": kind, "study": b,
@@ -361,6 +425,7 @@ class TuningService:
             if req_id is not None:
                 cached = self._reply_cache.get(b, {}).get(req_id)
                 if cached is not None:
+                    self.counters.add("replies_cached")
                     view = self.bank.studies[b]
                     return {**self._trial_json(view._trials[cached[0]]),
                             "cached": True}
@@ -376,6 +441,7 @@ class TuningService:
             b = self._row(name)
             if req_id is not None \
                     and req_id in self._reply_cache.get(b, {}):
+                self.counters.add("replies_cached")
                 return {"ok": True, "cached": True}
             self._check_writable()
             self._commit({"op": "trace", "study": b, "req_id": req_id})
@@ -427,6 +493,13 @@ class TuningService:
                 "op_seq": self.bank.op_seq,
                 "n_studies": len(self._names),
                 "wal_error": self.wal_error}
+
+    def stats(self) -> Dict[str, int]:
+        """The counters of this service and its bank since it started (a
+        reopened service starts at zero), for operators: ``GET /stats``.
+        Taken without the service lock, so a long commit does not hold
+        it up."""
+        return {**self.counters.snapshot(), **self.bank.counters.snapshot()}
 
     # --------------------------------------------------------- compaction
     def compact(self) -> Dict[str, Any]:
@@ -505,6 +578,8 @@ class _Handler(BaseHTTPRequestHandler):
         pass
 
     def _reply(self, status: int, payload: Dict[str, Any]) -> None:
+        if status >= 400:
+            self.service.counters.add(f"failed.{self._verb}")
         body = json.dumps(payload).encode()
         self.send_response(status)
         self.send_header("Content-Type", "application/json")
@@ -522,13 +597,27 @@ class _Handler(BaseHTTPRequestHandler):
             raise ServiceError(400, "request body is not valid JSON")
 
     def _route(self, method: str) -> None:
+        """One request, in the span ``mango.http`` (parse, service call
+        and reply), counted under ``requests.<verb>``."""
         svc = self.service
         parts = [unquote(p) for p in
                  urlparse(self.path).path.strip("/").split("/") if p]
+        self._verb = _verb(method, parts)
+        svc.counters.add(f"requests.{self._verb}")
+        study = parts[1] if len(parts) == 3 and parts[0] == "studies" else ""
+        with span("mango.http", verb=self._verb, study=study) as sp:
+            self._dispatch(method, parts, sp)
+
+    def _dispatch(self, method: str, parts: List[str], sp) -> None:
+        svc = self.service
         try:
+            body = self._body() if method == "POST" else {}
+            sp.set_metadata(req=_req_tag(body, svc._request_numbers))
             if method == "GET":
                 if parts == ["health"]:
                     return self._reply(200, svc.health())
+                if parts == ["stats"]:
+                    return self._reply(200, svc.stats())
                 if parts == ["studies"]:
                     return self._reply(200, svc.studies())
                 if len(parts) == 3 and parts[0] == "studies":
@@ -540,7 +629,6 @@ class _Handler(BaseHTTPRequestHandler):
                     if verb == "trials":
                         return self._reply(200, svc.trials(name))
             else:  # POST
-                body = self._body()
                 if parts == ["studies"]:
                     return self._reply(200, svc.create_study(
                         body["name"], body.get("sign", 1.0),
@@ -578,6 +666,26 @@ class _Handler(BaseHTTPRequestHandler):
 
     def do_POST(self):
         self._route("POST")
+
+
+def _verb(method: str, parts: List[str]) -> str:
+    """The request kind a path names, or ``other``."""
+    if len(parts) == 3 and parts[0] == "studies":
+        verb = parts[2]
+    elif parts == ["studies"]:
+        verb = "create" if method == "POST" else "studies"
+    elif parts == ["admin", "compact"]:
+        verb = "compact"
+    else:
+        verb = "/".join(parts)
+    return verb if verb in VERBS else "other"
+
+
+def _req_tag(body: Any, numbers) -> Any:
+    """What ties a request's spans together: the client's ``req_id``
+    (its retries share it), else the next of this service's numbers."""
+    rid = body.get("req_id") if isinstance(body, dict) else None
+    return str(rid) if rid is not None else next(numbers)
 
 
 def serve(data_dir, host: str = "127.0.0.1", port: int = 0,

@@ -3,6 +3,7 @@ exactly-once-effect dedup, degradation, HTTP layer, and the subprocess
 chaos kill/restart harness."""
 import json
 import os
+import shutil
 import threading
 import time
 
@@ -14,7 +15,10 @@ from repro.service.chaos import run as chaos_run
 from repro.service.client import (RemoteOptimizer, ServiceClient,
                                   ServiceError)
 from repro.service.recovery import WAL_FILE, wal_suffix
-from repro.service.server import CrashPoints, TuningService, serve
+from repro.analysis.sanitizers import set_debug_locks
+from repro.core.studybank import BANK_COUNTERS
+from repro.service.server import (SERVICE_COUNTERS, CrashPoints,
+                                  TuningService, serve)
 from repro.service.wal import (WriteAheadLog, encode_frame, read_records,
                                truncate_to)
 
@@ -125,9 +129,83 @@ def test_tell_dedup_and_ask_req_id_cache(tmp_path):
     assert not dup["applied"] and dup["value"] == 1.5
     assert len(wal_suffix(svc.data_dir)) == n_wal
     assert not svc.tell_failed("a", ids[0])["applied"]
+    assert svc.stats()["replies_cached"] == 3
     with pytest.raises(ServiceError) as ei:
         svc.tell("a", 999, 0.0)
     assert ei.value.status == 404
+    svc.close()
+
+
+def _tell_rounds(svc, name, rounds):
+    for _ in range(rounds):
+        for t in svc.ask(name, 2)["trials"]:
+            svc.tell(name, t["id"], float(t["params"]["x"]))
+
+
+def test_fit_and_obs_stage_counters(tmp_path):
+    """The fit runs every GP-family row and keeps the due ones; the obs
+    stage's cache holds across failed tells and not across a real one."""
+    svc = _svc(tmp_path)
+    svc.create_study("a")
+    _tell_rounds(svc, "a", 3)
+    s0 = svc.stats()
+    assert s0["fit.calls"] >= 1
+    assert s0["fit.rows_run"] == len(svc.bank._gp_fam_rows) * s0["fit.calls"]
+    assert 1 <= s0["fit.rows_due"] <= s0["fit.rows_run"]
+    held = svc.ask("a", 2)["trials"]              # after real tells: a miss
+    s1 = svc.stats()
+    assert s1["obs_stage.calls"] == s0["obs_stage.calls"] + 1
+    assert s1["obs_stage.hits"] == s0["obs_stage.hits"]
+    for t in held:
+        svc.tell_failed("a", t["id"])
+    held = svc.ask("a", 2)["trials"]              # only failed tells: a hit
+    s2 = svc.stats()
+    assert s2["obs_stage.calls"] == s1["obs_stage.calls"] + 1
+    assert s2["obs_stage.hits"] == s1["obs_stage.hits"] + 1
+    svc.tell("a", held[0]["id"], 0.5)
+    svc.tell_failed("a", held[1]["id"])
+    svc.ask("a", 2)                               # a real tell: a miss
+    s3 = svc.stats()
+    assert s3["obs_stage.hits"] == s2["obs_stage.hits"]
+    assert s3["factors_copy.bytes"] > s2["factors_copy.bytes"]
+    assert s3["obs_stage.ns"] >= s3["fit.ns"] + s3["factors_copy.ns"] > 0
+    svc.close()
+
+
+def test_lock_counters_and_held_assertions(tmp_path):
+    """One acquisition per outermost hold, re-entry included; the hold
+    covers the journal; ``assert_holds`` still sees the owner."""
+    prev = set_debug_locks(True)
+    try:
+        svc = _svc(tmp_path)
+        svc.create_study("a")
+        s0 = svc.stats()
+        with svc._lock:
+            with svc._lock:
+                svc.ask("a", 2)
+        s1 = svc.stats()
+        assert s1["lock.acquisitions"] == s0["lock.acquisitions"] + 1
+        assert s1["journal.appends"] == s0["journal.appends"] + 1
+        assert s1["lock.held_ns"] >= s1["journal.ns"] > 0
+        assert s1["lock.wait_ns"] >= 0
+        with pytest.raises(AssertionError):
+            svc._commit({"op": "trace", "study": 0, "req_id": None})
+        assert svc.compact()["op_seq"] == svc.bank.op_seq
+        svc.close()
+    finally:
+        set_debug_locks(prev)
+
+
+def test_reopened_copy_counts_from_zero(tmp_path):
+    svc = _svc(tmp_path)
+    svc.create_study("a")
+    _tell_rounds(svc, "a", 3)
+    assert svc.stats()["fit.calls"] >= 1
+    shutil.copytree(svc.data_dir, tmp_path / "copy")
+    copy = TuningService(tmp_path / "copy", crash=CrashPoints(""))
+    assert copy.stats() == dict.fromkeys(SERVICE_COUNTERS + BANK_COUNTERS, 0)
+    assert copy.ask("a", 2)["trials"] == svc.ask("a", 2)["trials"]
+    copy.close()
     svc.close()
 
 
@@ -455,6 +533,23 @@ def test_http_end_to_end(http_service):
     with pytest.raises(ServiceError) as ei:
         cl._request("POST", "/no/such/route", {})
     assert ei.value.status == 404
+
+
+def test_http_stats_are_the_service_counters(http_service):
+    base, svc = http_service
+    cl = ServiceClient(base)
+    cl.create_study("web")
+    ids = [t["id"] for t in cl.ask("web", n=2, req_id="s1")["trials"]]
+    cl.ask("web", n=2, req_id="s1")
+    cl.tell("web", ids[0], 0.5)
+    for path in ("/studies/nope/tell", "/no/such/route"):
+        with pytest.raises(ServiceError):
+            cl._request("POST", path, {"trial_id": 0, "value": 1.0})
+    got = cl.stats()
+    assert got == svc.stats()
+    assert got["requests.ask"] == 2 and got["requests.stats"] == 1
+    assert got["failed.tell"] == 1 and got["failed.other"] == 1
+    assert "failed.ask" not in got and got["replies_cached"] == 1
 
 
 def test_remote_optimizer_matches_local_bank(http_service):
