@@ -148,7 +148,7 @@ def run_retrace_sweep(max_obs=1024, n_mc=64, n_studies=2, seed=0):
     the one compile each entry point owes per bucket shape."""
     from repro.analysis.sanitizers import no_retrace
     from repro.core import StudyBank
-    from repro.core.studybank import _pow2
+    from repro.core.studybank import _pow2, row_buckets
 
     bank = StudyBank(_space(), n_studies, optimizer="bayesian", seed=seed,
                      mc_samples=n_mc)
@@ -193,12 +193,14 @@ def run_retrace_sweep(max_obs=1024, n_mc=64, n_studies=2, seed=0):
         # expected compiles per staged entry point: one per na bucket it is
         # dispatched at.  prescale_C's shape depends only on mc_samples (one
         # bucket for the whole sweep); absorb never runs (no trial is in
-        # flight at ask time); the fit program runs only at fit-due targets.
+        # flight at ask time); the fit program runs only at fit-due targets,
+        # once per row bucket of the GP sub-batch at each.
         nb = len(propose_buckets)
+        nr = len(row_buckets(len(bank._gp_fam_rows)))
         rep.expected = {"bank_factors": nb, "bank_prescale_X": nb,
                         "bank_prescale_C": 1, "bank_absorb": 0,
                         "bank_dist": nb, "bank_exp": nb, "bank_pick": nb,
-                        "fit_hypers_bank": len(fit_buckets)}
+                        "fit_hypers_bank": len(fit_buckets) * nr}
     retraces = rep.violations
     detail = rep.detail() or "all=expected"
     _emit("steady_state_retrace", float(retraces),

@@ -34,7 +34,10 @@ Bucketing contract: device buffers are padded to ``pow2(max(16, ...))``
 rows with ``n_obs``/``n_pending`` carried as masked ranks, so within a
 bucket the compiled program is reused ask after ask (the
 ``steady_state_retrace`` bench row asserts zero retraces across a
-64→1024-observation growth sweep, compiles at bucket edges aside).
+64→1024-observation growth sweep, compiles at bucket edges aside).  The
+hyperparameter fit runs only the studies due a refit, padded to a
+power-of-2 row bucket; the first fit at a bucket compiles the fit at
+every row bucket, so later fits of any due count compile nothing.
 """
 from __future__ import annotations
 
@@ -59,10 +62,11 @@ _MASK64 = (1 << 64) - 1
 
 # what the bank counts for operators (``TuningService.stats``): the obs
 # stage's calls and cache hits, the fits with the rows due and the rows
-# run, and the copy of the factors to the host
+# run, the fits that only compile a row bucket, and the copy of the
+# factors to the host
 BANK_COUNTERS = ("obs_stage.calls", "obs_stage.hits", "obs_stage.ns",
                  "fit.calls", "fit.rows_due", "fit.rows_run", "fit.ns",
-                 "factors_copy.ns", "factors_copy.bytes")
+                 "fit.warm_calls", "factors_copy.ns", "factors_copy.bytes")
 
 
 def _pow2(n: int) -> int:
@@ -70,6 +74,18 @@ def _pow2(n: int) -> int:
     while p < n:
         p *= 2
     return p
+
+
+def row_bucket(k: int, R: int) -> int:
+    """Rows the fit program runs for ``k`` due rows of a sub-batch of
+    ``R``: the power of 2 at or above ``k``, capped at ``R``."""
+    return min(1 << (k - 1).bit_length(), R)
+
+
+def row_buckets(R: int) -> List[int]:
+    """Every row bucket of a sub-batch of ``R`` rows: 1, 2, 4, ... below
+    ``R``, then ``R``."""
+    return sorted({row_bucket(k, R) for k in range(1, R + 1)})
 
 
 # strategy name -> dispatch family for the bank pipeline.  "gp" and
@@ -333,6 +349,7 @@ class StudyBank:
         self.seed = seed
         self.ledger = StudyLedger(n_studies, self.space.dim)
         self._gp_cache = None   # obs_stamp-keyed device state (staged ask)
+        self._fit_warmed = set()  # (na, R) whose fit row buckets compiled
         self.counters = Counters(BANK_COUNTERS)
         # monotonic operation sequence for journaled (WAL) deployments: the
         # last op applied through ``apply_op``; snapshots carry it so crash
@@ -374,6 +391,7 @@ class StudyBank:
         bank.seed = None
         bank.ledger = view._led
         bank._gp_cache = None
+        bank._fit_warmed = set()
         bank.counters = Counters(BANK_COUNTERS)
         bank.op_seq = 0
         bank.extra = None
@@ -713,9 +731,11 @@ class StudyBank:
         """Count-based fit schedule over the gp-family sub-batch: (re)fit
         hypers for every study whose observation count advanced
         ``refit_every`` past its last fit (or that never fit).  The fit
-        program runs over the whole sub-batch at the bucket shape —
-        selective write-back keeps non-due studies' frozen hypers (and
-        frozen y standardization) bit-stable.  Standardization scalars are
+        program runs over the due rows only, gathered on the host and
+        padded to a power-of-2 row bucket (``row_bucket``) with copies of
+        the first due row, whose results are dropped; write-back touches
+        the due rows alone, so non-due studies' frozen hypers (and frozen
+        y standardization) stay bit-stable.  Standardization scalars are
         computed on the host with the exact single-study op sequence
         (``_y_standardization``), so a study served by the bank
         standardizes bit-identically to the pre-refactor engine.
@@ -743,34 +763,61 @@ class StudyBank:
         if not due.any():
             return False
         from repro.core import gp as gp_lib
-        ym = led.y_mean[rows].copy()
-        ys = led.y_std[rows].copy()
         sel = np.nonzero(due)[0]
-        for i in sel:
-            ym[i], ys[i] = _y_standardization(yraw[i, :int(ko64[i])])
-        # the fit runs every row of the sub-batch; only the due rows
-        # keep its result
+        k, rb = len(sel), row_bucket(len(sel), len(rows))
+        # the due rows, then copies of the first due row up to the bucket
+        take = np.concatenate([sel, np.repeat(sel[:1], rb - k)])
+        g = np.asarray(rows)[take]
+        ym = led.y_mean[g].copy()
+        ys = led.y_std[g].copy()
+        for j, i in enumerate(sel):
+            ym[j], ys[j] = _y_standardization(yraw[i, :int(ko64[i])])
+        ym[k:], ys[k:] = ym[0], ys[0]
+        args = (Xd[take], yraw[take], mask[take], led.log_ls[g],
+                led.log_var[g], led.log_noise[g], ym, ys)
         c = self.counters
         c.add("fit.calls")
-        c.add("fit.rows_due", len(sel))
-        c.add("fit.rows_run", len(rows))
-        with span("mango.fit", rows_due=len(sel), rows_run=len(rows)), \
-                c.timed("fit.ns"):
-            lls, lv, ln = gp_lib.fit_hypers_bank(
-                Xd, yraw, mask, led.log_ls[rows], led.log_var[rows],
-                led.log_noise[rows], ym, ys, steps=self.fit_steps)
+        c.add("fit.rows_due", k)
+        c.add("fit.rows_run", rb)
+        with span("mango.fit", rows_due=k, rows_run=rb), c.timed("fit.ns"):
+            lls, lv, ln = gp_lib.fit_hypers_bank(*args, steps=self.fit_steps)
             # one explicit exit transfer for the three hyper arrays
             lls, lv, ln = jax.device_get((lls, lv, ln))
-        g = np.asarray(rows)[sel]
-        led.log_ls[g] = lls[sel]
-        led.log_var[g] = lv[sel]
-        led.log_noise[g] = ln[sel]
-        led.y_mean[g] = ym[sel]
-        led.y_std[g] = ys[sel]
+        self._warm_fit_buckets(args, rb, len(rows))
+        g = g[:k]
+        led.log_ls[g] = lls[:k]
+        led.log_var[g] = lv[:k]
+        led.log_noise[g] = ln[:k]
+        led.y_mean[g] = ym[:k]
+        led.y_std[g] = ys[:k]
         led.n_fit[g] = ko64[sel]
         led.have_fit[g] = 1
         led.obs_stamp += 1    # new hypers/standardization: factors stale
         return True
+
+    def _warm_fit_buckets(self, args, rb: int, R: int) -> None:
+        """Compile the fit at every other row bucket of this ``(na, R)``
+        the first time it fits there, so a later fit at any due count
+        finds its program compiled.  Each dispatch runs on copies of the
+        first row of ``args`` and its result is dropped: the ledger, the
+        RNG, the obs stamp and the journal are untouched, so a replayed
+        bank makes the same calls and reaches the same state.  The
+        dispatches go back to back and are waited for together, so the
+        device runs one while the host loads the next."""
+        key = (int(args[0].shape[1]), R)
+        if key in self._fit_warmed:
+            return
+        self._fit_warmed.add(key)
+        from repro.core import gp as gp_lib
+        outs = []
+        for b in row_buckets(R):
+            if b == rb:
+                continue
+            self.counters.add("fit.warm_calls")
+            outs.append(gp_lib.fit_hypers_bank(
+                *(np.repeat(a[:1], b, axis=0) for a in args),
+                steps=self.fit_steps))
+        jax.block_until_ready(outs)
 
     def _obs_stage(self, k_obs: np.ndarray, na: int):
         """Observation-dependent stages for every gp-family row (GP and

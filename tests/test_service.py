@@ -16,7 +16,7 @@ from repro.service.client import (RemoteOptimizer, ServiceClient,
                                   ServiceError)
 from repro.service.recovery import WAL_FILE, wal_suffix
 from repro.analysis.sanitizers import set_debug_locks
-from repro.core.studybank import BANK_COUNTERS
+from repro.core.studybank import BANK_COUNTERS, row_bucket, row_buckets
 from repro.service.server import (SERVICE_COUNTERS, CrashPoints,
                                   TuningService, serve)
 from repro.service.wal import (WriteAheadLog, encode_frame, read_records,
@@ -143,15 +143,46 @@ def _tell_rounds(svc, name, rounds):
 
 
 def test_fit_and_obs_stage_counters(tmp_path):
-    """The fit runs every GP-family row and keeps the due ones; the obs
-    stage's cache holds across failed tells and not across a real one."""
-    svc = _svc(tmp_path)
-    svc.create_study("a")
-    _tell_rounds(svc, "a", 3)
+    """The fit runs the due GP-family rows alone, padded to a power-of-2
+    row bucket; the obs stage's cache holds across failed tells and not
+    across a real one."""
+    svc = _svc(tmp_path, max_studies=8)
+    R = len(svc.bank._gp_fam_rows)
+    fits = []
+
+    def ask(name):
+        before = svc.stats()
+        out = svc.ask(name, 2)["trials"]
+        after = svc.stats()
+        assert after["fit.calls"] - before["fit.calls"] in (0, 1)
+        if after["fit.calls"] > before["fit.calls"]:
+            fits.append((after["fit.rows_due"] - before["fit.rows_due"],
+                         after["fit.rows_run"] - before["fit.rows_run"]))
+        return out
+
+    def observe(names, k):
+        for nm in names:
+            for v in np.linspace(-0.9, 1.9, k):
+                svc.observe(nm, {"x": float(v), "lr": 1e-3}, float(v) ** 2)
+
+    for nm in "abc":
+        svc.create_study(nm)
+    observe("abc", 2)
+    ask("a")                      # three never fit: three due
+    observe("ab", svc.bank.refit_every)
+    ask("c")                      # a and b advanced refit_every: two due
+    observe("c", svc.bank.refit_every)
+    for t in ask("b"):            # c alone: one due
+        svc.tell("b", t["id"], float(t["params"]["x"]))
+    assert [due for due, _ in fits] == [3, 2, 1]
+    for due, run in fits:
+        assert run == row_bucket(due, R)
+        assert run < 2 * due or run == R
     s0 = svc.stats()
-    assert s0["fit.calls"] >= 1
-    assert s0["fit.rows_run"] == len(svc.bank._gp_fam_rows) * s0["fit.calls"]
+    assert s0["fit.calls"] == len(fits)
+    assert s0["fit.rows_run"] == sum(run for _, run in fits)
     assert 1 <= s0["fit.rows_due"] <= s0["fit.rows_run"]
+    assert s0["fit.warm_calls"] >= len(row_buckets(R)) - 1
     held = svc.ask("a", 2)["trials"]              # after real tells: a miss
     s1 = svc.stats()
     assert s1["obs_stage.calls"] == s0["obs_stage.calls"] + 1

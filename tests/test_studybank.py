@@ -311,6 +311,122 @@ def test_bucket_shapes_shared_across_bank():
 
 
 # --------------------------------------------------------------------------- #
+# the fit runs the due rows alone, at a power-of-2 row bucket
+# --------------------------------------------------------------------------- #
+FIT_FIELDS = ("log_ls", "log_var", "log_noise", "y_mean", "y_std", "n_fit",
+              "have_fit")
+DUE_SETS = [(2,), (0, 4), (1, 3, 5)]
+
+
+def _observe(bank, b, k, rng):
+    for _ in range(k):
+        p = {"x": float(rng.uniform(0, 1)), "y": float(rng.uniform(-1, 1))}
+        bank.study(b).observe_params(p, _objective(p) + 0.01 * rng.normal())
+
+
+def _gp_bank(seed=5):
+    """Six GP-BUCB studies of 20..35 observations (bucket na = 64)."""
+    rng = np.random.default_rng(seed)
+    bank = StudyBank(SPACE, 6, optimizer="bayesian", seed=seed,
+                     mc_samples=32)
+    for b in range(6):
+        _observe(bank, b, 20 + 3 * b, rng)
+    return bank, rng
+
+
+def _ask_and_tell(bank, rng):
+    for b, ts in enumerate(bank.ask_all(1)):
+        for t in ts:
+            bank.tell(b, t.id, _objective(t.params) + 0.01 * rng.normal())
+
+
+@pytest.mark.parametrize("due", DUE_SETS)
+def test_due_row_fit_matches_whole_sub_batch_fit(due):
+    """Fitting the gathered due rows, padded to their row bucket, gives
+    each due row the hypers the fit over the whole sub-batch gives it;
+    rows not due keep their fit state bit for bit."""
+    from repro.core import gp as gp_lib
+    from repro.core.studybank import _y_standardization, row_bucket
+
+    bank, rng = _gp_bank()
+    _ask_and_tell(bank, rng)                  # the first fit: every row
+    led = bank.ledger
+    for b in due:
+        _observe(bank, b, bank.refit_every, rng)
+    gpr = bank._gp_fam_rows
+    ko = led.n_observed()[gpr].astype(np.int32)
+    Xd, yraw, mask = bank._gather_obs(ko, 64, gpr)
+    ym, ys = led.y_mean[gpr].copy(), led.y_std[gpr].copy()
+    for i in due:
+        ym[i], ys[i] = _y_standardization(yraw[i, :ko[i]])
+    whole = [np.asarray(a) for a in gp_lib.fit_hypers_bank(
+        Xd, yraw, mask, led.log_ls[gpr], led.log_var[gpr],
+        led.log_noise[gpr], ym, ys, steps=bank.fit_steps)]
+    before = {f: getattr(led, f).copy() for f in FIT_FIELDS}
+    run0 = bank.counters.snapshot()["fit.rows_run"]
+    assert bank._fit_if_due(Xd, yraw, mask, ko, gpr)
+    assert (bank.counters.snapshot()["fit.rows_run"] - run0
+            == row_bucket(len(due), len(gpr)))
+    for i, b in enumerate(gpr):
+        if i in due:
+            for f, w in zip(("log_ls", "log_var", "log_noise"), whole):
+                np.testing.assert_allclose(getattr(led, f)[b], w[i],
+                                           rtol=1e-6, err_msg=f)
+            assert (led.y_mean[b], led.y_std[b]) == (ym[i], ys[i])
+            assert led.n_fit[b] == ko[i]
+        else:
+            for f in FIT_FIELDS:
+                np.testing.assert_array_equal(getattr(led, f)[b],
+                                              before[f][b], err_msg=f)
+
+
+def test_due_row_fits_compile_nothing_after_the_first():
+    """The first fit at a bucket compiles the fit at every row bucket;
+    later fits of 1, 2 and 3 due rows add no jit cache entry.  The warm
+    dispatches leave the ledger, the obs stamp, the RNG and the op
+    sequence as they found them."""
+    from repro.analysis.sanitizers import no_retrace
+    from repro.core import gp as gp_lib
+    from repro.core.studybank import row_buckets
+
+    gp_lib.fit_hypers_bank.clear_cache()      # no bucket compiled before
+    bank, rng = _gp_bank()
+    led = bank.ledger
+    warm = bank._warm_fit_buckets
+    seen = []
+
+    def state():
+        return ({k: v.copy() for k, v in vars(led).items()
+                 if isinstance(v, np.ndarray)},
+                led.obs_stamp, bank._rng.bit_generator.state, bank.op_seq)
+
+    def watched(*a):
+        s0 = state()
+        warm(*a)
+        s1 = state()
+        assert s1[0].keys() == s0[0].keys()
+        for k in s0[0]:
+            np.testing.assert_array_equal(s1[0][k], s0[0][k], err_msg=k)
+        assert s1[1:] == s0[1:]
+        seen.append(a[1])
+
+    bank._warm_fit_buckets = watched
+    _ask_and_tell(bank, rng)                  # the first fit: every row
+    assert seen == [6]
+    warmed = bank.counters.snapshot()["fit.warm_calls"]
+    assert warmed == len(row_buckets(6)) - 1 == 3
+    calls = bank.counters.snapshot()["fit.calls"]
+    with no_retrace():
+        for due in DUE_SETS:
+            for b in due:
+                _observe(bank, b, bank.refit_every, rng)
+            _ask_and_tell(bank, rng)
+    snap = bank.counters.snapshot()
+    assert snap["fit.calls"] == calls + len(DUE_SETS)
+    assert snap["fit.warm_calls"] == warmed
+
+
+# --------------------------------------------------------------------------- #
 # rng kind tag
 # --------------------------------------------------------------------------- #
 def test_pack_rng_state_rejects_non_pcg64():

@@ -154,3 +154,21 @@ def test_fit_hypers_bank_compiles_for_v5e(one_chip, served):
         sp(rows), sp(rows), sp(rows), sp(rows), steps=40).compile()
     print(compiled.memory_analysis())
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("rows", [1, 2])
+def test_fit_hypers_bank_row_buckets_compile_for_v5e(one_chip, served, rows):
+    """The fit at the small row buckets that hold the usual one or two
+    due studies, at na = 1024."""
+    from repro.core import gp
+
+    d, _, _ = served
+    na = 1024
+
+    def sp(*shape):
+        return _spec(one_chip, shape)
+
+    compiled = gp.fit_hypers_bank.lower(
+        sp(rows, na, d), sp(rows, na), sp(rows, na), sp(rows, d),
+        sp(rows), sp(rows), sp(rows), sp(rows), steps=40).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e9
