@@ -62,11 +62,16 @@ _MASK64 = (1 << 64) - 1
 
 # what the bank counts for operators (``TuningService.stats``): the obs
 # stage's calls and cache hits, the fits with the rows due and the rows
-# run, the fits that only compile a row bucket, and the copy of the
-# factors to the host
+# run, the fits that only compile a row bucket, the factor program with
+# the rows it factored, their observations and pending rows, the slots
+# of their bucket and the rows past the condition limit, and the copy of
+# the factors to the host
 BANK_COUNTERS = ("obs_stage.calls", "obs_stage.hits", "obs_stage.ns",
                  "fit.calls", "fit.rows_due", "fit.rows_run", "fit.ns",
-                 "fit.warm_calls", "factors_copy.ns", "factors_copy.bytes")
+                 "fit.warm_calls", "factors.calls", "factors.ns",
+                 "factors.rows", "factors.obs", "factors.slots",
+                 "factors.ill_conditioned", "factors_copy.ns",
+                 "factors_copy.bytes")
 
 
 def _pow2(n: int) -> int:
@@ -779,7 +784,8 @@ class StudyBank:
         c.add("fit.calls")
         c.add("fit.rows_due", k)
         c.add("fit.rows_run", rb)
-        with span("mango.fit", rows_due=k, rows_run=rb), c.timed("fit.ns"):
+        with span("mango.fit", rows_due=k, rows_run=rb, na=Xd.shape[1]), \
+                c.timed("fit.ns"):
             lls, lv, ln = gp_lib.fit_hypers_bank(*args, steps=self.fit_steps)
             # one explicit exit transfer for the three hyper arrays
             lls, lv, ln = jax.device_get((lls, lv, ln))
@@ -853,17 +859,26 @@ class StudyBank:
         ls = np.exp(led.log_ls[gpr]).astype(np.float32)
         var = np.exp(led.log_var[gpr]).astype(np.float32)
         noise = (np.exp(led.log_noise[gpr]) + 1e-5).astype(np.float32)
-        with span("mango.factors"):
-            L, Linv, cond = gp_lib.bank_factors(Xd, mask, ls, var, noise)
+        c = self.counters
+        rows = len(gpr)
+        obs = int(ko.sum()) + int(led.n_pending()[gpr].sum())
+        c.add("factors.calls")
+        c.add("factors.rows", rows)
+        c.add("factors.obs", obs)
+        c.add("factors.slots", rows * na)
+        with span("mango.factors", na=na, rows=rows, obs=obs):
+            with c.timed("factors.ns"):
+                L, Linv, cond = gp_lib.bank_factors(Xd, mask, ls, var,
+                                                    noise)
+                # the copy below waits for the factors anyway: waiting
+                # here keeps the copy's time the transfer's
+                jax.block_until_ready((L, Linv, cond))
             Xs = gp_lib.bank_prescale_X(Xd, ls)
-            # the copy below waits for the factors anyway: waiting here
-            # keeps the copy's time the transfer's
-            jax.block_until_ready((L, Linv, cond))
         led.ensure_gp_capacity(na)
         nbytes = int(L.nbytes + Linv.nbytes + cond.nbytes)
-        self.counters.add("factors_copy.bytes", nbytes)
+        c.add("factors_copy.bytes", nbytes)
         with span("mango.factors_copy", bytes=nbytes), \
-                self.counters.timed("factors_copy.ns"):
+                c.timed("factors_copy.ns"):
             L_host, Linv_host, cond_host = jax.device_get((L, Linv, cond))
             led.L[gpr, :na, :na] = L_host
             led.Linv[gpr, :na, :na] = Linv_host
@@ -873,17 +888,20 @@ class StudyBank:
             "ls": jnp.asarray(ls), "var": jnp.asarray(var),
             "noise": jnp.asarray(noise),
             "cond": np.asarray(cond_host, np.float64)}
-        self._warn_if_ill_conditioned(cache["cond"], gpr)
+        self._count_ill_conditioned(cache["cond"], gpr)
         return cache
 
-    def _warn_if_ill_conditioned(self, cond: np.ndarray,
-                                 gpr: np.ndarray) -> None:
+    def _count_ill_conditioned(self, cond: np.ndarray,
+                               gpr: np.ndarray) -> None:
+        """Count the factored rows whose condition estimate passes
+        ``scoring.COND_PROXY_WARN``, or is NaN where the factorization
+        broke down, at every factoring; warn at the first such row of the
+        bank's life."""
         import warnings
         from repro.core import scoring
-        if getattr(self, "_cond_warned", False):
-            return
-        bad = np.nonzero(cond > scoring.COND_PROXY_WARN)[0]
-        if len(bad):
+        bad = np.nonzero(~(cond <= scoring.COND_PROXY_WARN))[0]
+        self.counters.add("factors.ill_conditioned", len(bad))
+        if len(bad) and not getattr(self, "_cond_warned", False):
             self._cond_warned = True
             b = int(gpr[bad[0]])
             warnings.warn(

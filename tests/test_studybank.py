@@ -426,6 +426,74 @@ def test_due_row_fits_compile_nothing_after_the_first():
     assert snap["fit.warm_calls"] == warmed
 
 
+def test_factor_counters_add_up():
+    """One factoring per obs-stage miss, of every GP-family row at the
+    ask's bucket: ``factors.slots`` grows by rows x na on each miss and
+    ``factors.obs`` by the rows' observations and pending trials."""
+    from repro.core.studybank import _pow2
+
+    bank, rng = _gp_bank()
+    led = bank.ledger
+    held = []
+    for step in range(6):
+        s0 = bank.counters.snapshot()
+        ko, kp = led.n_observed(), led.n_pending()
+        pend_cap = max(4, -(-int(kp.max()) // 4) * 4)
+        na = _pow2(max(16, int(ko.max()) + pend_cap + 1))
+        out = bank.ask_all(1)
+        s1 = bank.counters.snapshot()
+        miss = (s1["obs_stage.calls"] - s0["obs_stage.calls"]
+                - (s1["obs_stage.hits"] - s0["obs_stage.hits"]))
+        assert s1["factors.calls"] - s0["factors.calls"] == miss
+        assert s1["factors.rows"] - s0["factors.rows"] == 6 * miss
+        assert s1["factors.slots"] - s0["factors.slots"] == 6 * na * miss
+        assert (s1["factors.obs"] - s0["factors.obs"]
+                == miss * int(ko.sum() + kp.sum()))
+        if miss:
+            assert s1["factors.ns"] > s0["factors.ns"]
+        for b, ts in enumerate(out):        # real tells, then held trials
+            for t in ts:
+                if step % 2:
+                    held.append((b, t.id))
+                else:
+                    bank.tell(b, t.id, _objective(t.params))
+    snap = bank.counters.snapshot()
+    assert snap["factors.calls"] == (snap["obs_stage.calls"]
+                                     - snap["obs_stage.hits"]) > 0
+    assert snap["obs_stage.hits"] > 0 and held
+    assert 0 < snap["factors.obs"] <= snap["factors.slots"]
+    assert snap["factors.ill_conditioned"] == 0
+
+
+@pytest.mark.parametrize("log_var", [0.0, 2.0])
+def test_ill_conditioned_rows_are_counted_at_every_factoring(log_var):
+    """A near-duplicate history with the noise at its floor passes the
+    condition limit (``log_var`` 0) or breaks the float32 factorization
+    (2, a NaN estimate); either counts at each factoring, and warns
+    once."""
+    import warnings
+
+    rng = np.random.default_rng(9)
+    bank = StudyBank(SPACE, 2, optimizer="bayesian", seed=9, mc_samples=32)
+    _observe(bank, 0, 20, rng)
+    for i in range(60):                       # 60 points 1e-7 apart
+        bank.study(1).observe_params({"x": 0.5 + 1e-7 * i, "y": 0.1},
+                                     0.01 * rng.normal())
+    bank.ask_all(1)                           # the first fit of both
+    assert bank.counters.snapshot()["factors.ill_conditioned"] == 0
+    led = bank.ledger
+    led.log_var[1], led.log_noise[1] = log_var, -30.0
+    with warnings.catch_warnings(record=True) as w:
+        warnings.simplefilter("always")
+        for k in (1, 2):                      # a tell short of a refit
+            bank.study(1).observe_params({"x": 0.5, "y": 0.1}, 0.0)
+            bank.ask_all(1)
+            assert bank.counters.snapshot()["factors.ill_conditioned"] == k
+    cond = bank._gp_cache["cond"]
+    assert cond[0] < 1e7 and not cond[1] <= 1e7
+    assert sum("condition estimate" in str(x.message) for x in w) == 1
+
+
 # --------------------------------------------------------------------------- #
 # rng kind tag
 # --------------------------------------------------------------------------- #
