@@ -7,7 +7,7 @@ studies, not one notebook loop.  This module gives the engine that shape:
   * ``StudyLedger`` — a registered pytree of fixed-capacity numpy arrays
     holding every study's trial ledger (encoded X rows, raw y, status,
     completion order), counters, per-study RNG state, GP hyperparameter /
-    fit-schedule state, and the last Cholesky factors ``L``/``L⁻¹``.
+    fit-schedule state.
     ``AskTellOptimizer`` is a *view* into one row of a ledger (a bank of
     one by default), so the single-study API is unchanged while the state
     itself is array-shaped.
@@ -62,16 +62,14 @@ _MASK64 = (1 << 64) - 1
 
 # what the bank counts for operators (``TuningService.stats``): the obs
 # stage's calls and cache hits, the fits with the rows due and the rows
-# run, the fits that only compile a row bucket, the factor program with
-# the rows it factored, their observations and pending rows, the slots
-# of their bucket and the rows past the condition limit, and the copy of
-# the factors to the host
+# run, the fits that only compile a row bucket, and the factor program
+# with the rows it factored, their observations and pending rows, the
+# slots of their bucket and the rows past the condition limit
 BANK_COUNTERS = ("obs_stage.calls", "obs_stage.hits", "obs_stage.ns",
                  "fit.calls", "fit.rows_due", "fit.rows_run", "fit.ns",
                  "fit.warm_calls", "factors.calls", "factors.ns",
                  "factors.rows", "factors.obs", "factors.slots",
-                 "factors.ill_conditioned", "factors_copy.ns",
-                 "factors_copy.bytes")
+                 "factors.ill_conditioned")
 
 
 def _pow2(n: int) -> int:
@@ -173,7 +171,7 @@ class StudyLedger:
         "X", "y", "status", "obs_seq",
         "n_trials", "ask_count", "obs_count", "n_failed",
         "log_ls", "log_var", "log_noise", "have_fit", "n_fit",
-        "y_mean", "y_std", "L", "Linv", "rng_state",
+        "y_mean", "y_std", "rng_state",
     )
 
     # Monotone observation stamp: bumped by every mutation that can change
@@ -187,8 +185,7 @@ class StudyLedger:
     # serialized) so unflattened/restored ledgers start valid at 0.
     obs_stamp = 0
 
-    def __init__(self, n_studies: int, dim: int, capacity: int = 16,
-                 gp_capacity: int = 16):
+    def __init__(self, n_studies: int, dim: int, capacity: int = 16):
         if n_studies < 1:
             raise ValueError("n_studies must be >= 1")
         B, d = int(n_studies), int(dim)
@@ -212,11 +209,6 @@ class StudyLedger:
         self.n_fit = np.zeros((B,), np.int64)
         self.y_mean = np.zeros((B,), np.float32)
         self.y_std = np.ones((B,), np.float32)
-        # ---- last Cholesky factors from the bank propose program ----------
-        gcap = _pow2(max(16, gp_capacity))
-        eye = np.eye(gcap, dtype=np.float32)
-        self.L = np.tile(eye, (B, 1, 1))
-        self.Linv = np.tile(eye, (B, 1, 1))
         # ---- per-study RNG streams (synced from the views at save time) ---
         self.rng_state = np.zeros((B, 6), _U64)
 
@@ -224,10 +216,6 @@ class StudyLedger:
     @property
     def capacity(self) -> int:
         return self.X.shape[1]
-
-    @property
-    def gp_capacity(self) -> int:
-        return self.L.shape[1]
 
     def ensure_capacity(self, n: int) -> None:
         cap = self.capacity
@@ -245,19 +233,6 @@ class StudyLedger:
         obs_seq[:, :cap] = self.obs_seq
         self.X, self.y, self.status, self.obs_seq = X, y, status, obs_seq
 
-    def ensure_gp_capacity(self, n: int) -> None:
-        gcap = self.gp_capacity
-        if n <= gcap:
-            return
-        new = _pow2(n)
-        B = self.n_studies
-        eye = np.eye(new, dtype=np.float32)
-        L = np.tile(eye, (B, 1, 1))
-        L[:, :gcap, :gcap] = self.L
-        Linv = np.tile(eye, (B, 1, 1))
-        Linv[:, :gcap, :gcap] = self.Linv
-        self.L, self.Linv = L, Linv
-
     # ----------------------------------------------------------- per-study
     def reset_study(self, b: int) -> None:
         """Clear one study's row back to the cold state (load target)."""
@@ -274,9 +249,6 @@ class StudyLedger:
         self.have_fit[b] = 0
         self.n_fit[b] = 0
         self.y_mean[b], self.y_std[b] = 0.0, 1.0
-        g = self.gp_capacity
-        self.L[b] = np.eye(g, dtype=np.float32)
-        self.Linv[b] = np.eye(g, dtype=np.float32)
         self.rng_state[b] = 0
 
     def n_observed(self) -> np.ndarray:
@@ -844,8 +816,9 @@ class StudyBank:
             return cache if hit else self._factor_obs(k_obs, na, signs)
 
     def _factor_obs(self, k_obs: np.ndarray, na: int, signs: tuple):
-        """The obs stage's cache miss: gather, fit if due, factor, and
-        copy the factors to the ledger."""
+        """The obs stage's cache miss: gather, fit if due, factor.  The
+        factors stay on the device, in the cache the pick reads; only
+        the per-row condition estimates come to the host."""
         led = self.ledger
         gpr = self._gp_fam_rows
         ko = k_obs[gpr]
@@ -870,24 +843,17 @@ class StudyBank:
             with c.timed("factors.ns"):
                 L, Linv, cond = gp_lib.bank_factors(Xd, mask, ls, var,
                                                     noise)
-                # the copy below waits for the factors anyway: waiting
-                # here keeps the copy's time the transfer's
+                # the condition estimates' readback waits for the factors
+                # anyway: waiting here keeps the program's time in this
+                # counter
                 jax.block_until_ready((L, Linv, cond))
             Xs = gp_lib.bank_prescale_X(Xd, ls)
-        led.ensure_gp_capacity(na)
-        nbytes = int(L.nbytes + Linv.nbytes + cond.nbytes)
-        c.add("factors_copy.bytes", nbytes)
-        with span("mango.factors_copy", bytes=nbytes), \
-                c.timed("factors_copy.ns"):
-            L_host, Linv_host, cond_host = jax.device_get((L, Linv, cond))
-            led.L[gpr, :na, :na] = L_host
-            led.Linv[gpr, :na, :na] = Linv_host
         cache = self._gp_cache = {
             "key": (led.obs_stamp, na, signs), "Xs": Xs, "z": jnp.asarray(z),
             "mask": jnp.asarray(mask), "L": L, "Linv": Linv,
             "ls": jnp.asarray(ls), "var": jnp.asarray(var),
             "noise": jnp.asarray(noise),
-            "cond": np.asarray(cond_host, np.float64)}
+            "cond": np.asarray(jax.device_get(cond), np.float64)}
         self._count_ill_conditioned(cache["cond"], gpr)
         return cache
 
@@ -1120,6 +1086,9 @@ class StudyBank:
                 raise ValueError(
                     f"bank holds {self.n_studies} studies, checkpoint has "
                     f"{meta['n_studies']}")
+            # older snapshots also carry the factors (``led_L``,
+            # ``led_Linv``): the obs stage recomputes them, so they are
+            # left unread
             arrays = {name: z[f"led_{name}"]
                       for name in StudyLedger.ARRAY_FIELDS}
         led = self.ledger

@@ -300,12 +300,14 @@ def test_mixed_bank_parity_with_homogeneous_banks():
 
 def test_bucket_shapes_shared_across_bank():
     """Studies of different sizes share one bucket: the bank ask pads every
-    study to the same power-of-2 capacity, and the ledger factor buffers
-    grow to hold it."""
+    study to the same power-of-2 capacity, and the obs stage factors every
+    row at it."""
     bank = _seeded_bank("bayesian", [EDGE - 1, EDGE, EDGE + 1])
     bank.ask_all(1)
     # all three studies proposed through one program at one bucket shape
-    assert bank.ledger.gp_capacity >= 64
+    na = bank._gp_cache["key"][1]
+    assert na >= 64
+    assert bank._gp_cache["L"].shape == (3, na, na)
     for b in range(3):
         assert len(bank.study(b).pending_trials()) == 1
 
@@ -492,6 +494,74 @@ def test_ill_conditioned_rows_are_counted_at_every_factoring(log_var):
     cond = bank._gp_cache["cond"]
     assert cond[0] < 1e7 and not cond[1] <= 1e7
     assert sum("condition estimate" in str(x.message) for x in w) == 1
+
+
+# --------------------------------------------------------------------------- #
+# the factors stay on the device
+# --------------------------------------------------------------------------- #
+def test_ledger_and_snapshot_hold_no_factors(tmp_path):
+    """The Cholesky factors live in the obs-stage cache alone: after an
+    ask at na = 64 the ledger has no ``L``/``Linv`` and a snapshot no
+    ``led_L``/``led_Linv``."""
+    bank, rng = _gp_bank()
+    _ask_and_tell(bank, rng)
+    assert bank._gp_cache["key"][1] >= 64
+    assert not hasattr(bank.ledger, "L")
+    assert not hasattr(bank.ledger, "Linv")
+    path = tmp_path / "fleet.npz"
+    bank.save(path)
+    with np.load(path) as z:
+        assert "led_X" in z.files
+        assert "led_L" not in z.files and "led_Linv" not in z.files
+
+
+def test_snapshot_with_factors_still_loads(tmp_path):
+    """A snapshot written while the ledger held the factors carries
+    ``led_L``/``led_Linv``; it loads, they are left unread, and the next
+    ``ask_all`` equals that of the bank that wrote it, trial for trial."""
+    bank, rng = _gp_bank()
+    for _ in range(2):
+        _ask_and_tell(bank, rng)
+    path = tmp_path / "fleet.npz"
+    bank.save(path, iteration=7)
+    with np.load(path) as z:
+        arrays = {k: z[k] for k in z.files}
+    B, na = bank.n_studies, bank._gp_cache["key"][1]
+    arrays["led_L"] = rng.normal(size=(B, na, na)).astype(np.float32)
+    arrays["led_Linv"] = rng.normal(size=(B, na, na)).astype(np.float32)
+    old = tmp_path / "old.npz"
+    np.savez(old, **arrays)
+    fresh = StudyBank(SPACE, 6, optimizer="bayesian", seed=5,
+                      mc_samples=32)
+    assert fresh.load(old) == 7
+    assert not hasattr(fresh.ledger, "L")
+    want, got = bank.ask_all(2), fresh.ask_all(2)
+    assert [[(t.id, t.params) for t in ts] for ts in got] \
+        == [[(t.id, t.params) for t in ts] for ts in want]
+
+
+def test_obs_stage_miss_copies_no_factors_to_the_host(monkeypatch):
+    """An obs-stage miss brings only per-row numbers to the host (the
+    condition estimates, the fit's hypers, the picks): no array of rank
+    3, such as the ``(R, na, na)`` factors, passes ``jax.device_get``."""
+    import jax
+
+    bank, rng = _gp_bank()
+    _ask_and_tell(bank, rng)
+    shapes = []
+    real = jax.device_get
+
+    def recording(x):
+        shapes.extend(np.shape(a) for a in jax.tree_util.tree_leaves(x))
+        return real(x)
+
+    monkeypatch.setattr(jax, "device_get", recording)
+    calls = bank.counters.snapshot()["factors.calls"]
+    _ask_and_tell(bank, rng)                 # after real tells: a miss
+    assert bank.counters.snapshot()["factors.calls"] == calls + 1
+    assert bank._gp_cache["L"].ndim == 3
+    assert (6,) in shapes                    # the condition estimates
+    assert max(len(s) for s in shapes) < 3, shapes
 
 
 # --------------------------------------------------------------------------- #
