@@ -6,18 +6,18 @@ cache, runs the quick grid, and pipes this tool's markdown table into
 push to a branch.
 
     python benchmarks/bench_delta.py OLD.json NEW.json \
-        [--threshold 1.15] [--gate 'pallas_rescore_*:1.25' ...]
+        [--threshold 1.15] [--gate 'studies_per_sec_256:1.25' ...]
 
 Rows are matched by ``name``.  Two de-noising mechanisms make the deltas
 meaningful on shared CI runners:
 
   * the benchmark itself times paired paths with *interleaved* median-of-N
-    reps (``proposal_latency._interleaved_medians``), so CPU-share
-    throttling bursts hit both paths of a pair equally within one run;
-  * rows with a same-run baseline partner (``*_fused`` -> ``*_host``/
-    ``*_seed``, ``*_downdate`` -> ``*_full``, ``kinv_f64_*`` ->
-    ``kinv_f32_*``, ``refit_warm`` -> ``refit_cold``) are compared as
-    *ratios to that baseline* rather than absolute microseconds — a run
+    reps (``multi_study._interleaved_medians``), so CPU-share throttling
+    bursts hit both paths of a pair equally within one run;
+  * rows with a same-run baseline partner (``studies_per_sec_*`` ->
+    ``multi_study_loop_*``, ``autotune_ask_gp`` -> ``autotune_ask_random``)
+    are compared as *ratios to that baseline* rather than absolute
+    microseconds — a run
     that is globally 2x slower (noisy neighbor) moves numerator and
     denominator together and produces no false flag.  Such rows are marked
     ``rel`` in the table; rows without a partner fall back to the raw
@@ -32,8 +32,8 @@ if any gated row regresses beyond its own ratio, the table is still
 printed but the exit code is 2.  Rows serving as someone's normalization
 denominator are exempt from gating (their comparison is raw microseconds
 — the very noise the normalization cancels), so in practice the CI
-``pallas_rescore_*:1.25`` gate blocks on the *downdate-vs-full ratio*
-regressing >25%; a uniform slowdown of both kernels stays advisory.
+``studies_per_sec_256:1.25`` gate blocks on the *bank-vs-loop ratio*
+regressing >25%; a uniform slowdown of both arms stays advisory.
 """
 from __future__ import annotations
 
@@ -44,15 +44,6 @@ import sys
 
 # derived row prefix -> same-run baseline row prefix (first match wins)
 BASELINES = [
-    ("proposal_fused", "proposal_seed"),
-    ("pallas_pending_fused", "pallas_pending_host"),
-    ("pallas_rescore_downdate", "pallas_rescore_full"),
-    ("clustering_fused", "clustering_host"),
-    ("tpe_fused", "tpe_host"),
-    ("tpe_pallas", "tpe_host"),
-    ("kinv_f64_schur", "kinv_f32_schur"),
-    ("refit_warm", "refit_cold"),
-    ("single_study_asks", "single_study_random"),
     ("studies_per_sec", "multi_study_loop"),
     ("autotune_ask_gp", "autotune_ask_random"),
 ]

@@ -19,11 +19,10 @@ identical budget, and larger budgets shift both arms toward the same
 elementwise-scoring floor.  The timed op is the steady-state ask: each
 rep's proposals are told *failed* in the untimed setup slot, so
 observation counts — and therefore every device shape and the fit
-schedule — stay frozen across reps.  Rows are timed
-interleaved (same convention as ``proposal_latency``) so CPU-share
-throttling hits both arms equally; ``bench_delta`` normalizes the
-``studies_per_sec`` rows against the same-run loop row, which is what the
-CI gate (``studies_per_sec_256:1.25``) blocks on.  Acceptance target:
+schedule — stay frozen across reps.  Rows are timed interleaved so
+CPU-share throttling hits both arms equally; ``bench_delta`` normalizes
+the ``studies_per_sec`` rows against the same-run loop row, which is what
+the CI gate (``studies_per_sec_256:1.25``) blocks on.  Acceptance target:
 bank >= 50x the loop at B=256.
 
 ``steady_state_retrace``: the zero-retrace proof for the shape-bucket
@@ -57,9 +56,9 @@ def _emit(name, us, derived):
 
 
 def _interleaved_medians(calls, reps=3, setups=None):
-    """Median seconds per call, calls interleaved within each rep (see
-    ``proposal_latency._interleaved_medians`` — same throttle-resistant
-    convention).  ``setups[i]`` runs untimed before each timed call."""
+    """Median seconds per call, calls interleaved within each rep, so a
+    CPU-share throttling burst hits every arm alike.  ``setups[i]`` runs
+    untimed before each timed call."""
     samples = [[] for _ in calls]
     for i, c in enumerate(calls):        # warmup: compile the timed path
         if setups is not None and setups[i] is not None:

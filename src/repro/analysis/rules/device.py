@@ -17,8 +17,8 @@ from repro.analysis.lint import (Finding, Module, Rule, call_name,
 from repro.analysis.rules import register
 
 # fused-path files: where device values flow and host syncs hide
-_DEVICE_FILES = ("gp.py", "acquisition.py", "tpe.py", "scoring.py",
-                 "studybank.py", "kmeans.py", "kernels")
+_DEVICE_FILES = ("gp.py", "tpe.py", "scoring.py", "studybank.py",
+                 "kmeans.py", "kernels")
 
 # call-name shapes that produce device (JAX) values in this repo
 _DEVICE_TERMINAL_PREFIXES = ("bank_", "fused_", "fit_hypers", "_dispatch")

@@ -9,4 +9,3 @@ __all__ = ["ParamSpace", "loguniform", "Int", "LogInt", "Choice",
            "CHOICE_KEY", "AskTellOptimizer", "Trial",
            "StudyBank", "StudyLedger", "Tuner", "TunerResults",
            "AsyncTuner"]
-from repro.core import tpe as _tpe  # registers optimizer="tpe"

@@ -45,7 +45,6 @@ class AsyncTuner:
                  mc_samples: Optional[int] = None,
                  poll_interval: float = 0.01, refit_every: int = 8,
                  optimizer: str = "bayesian", fit_steps: int = 40,
-                 use_pallas: bool = False,
                  domain_size: Optional[float] = None,
                  early_stopping: Optional[Callable[[TunerResults], bool]]
                  = None,
@@ -69,8 +68,7 @@ class AsyncTuner:
             self.opt = AskTellOptimizer(
                 param_space, optimizer=optimizer, seed=seed,
                 domain_size=domain_size, mc_samples=mc_samples,
-                fit_steps=fit_steps, use_pallas=use_pallas,
-                refit_every=refit_every,
+                fit_steps=fit_steps, refit_every=refit_every,
                 strategy_kwargs=strategy_kwargs)
         self.space = self.opt.space
         if checkpoint_path and Path(checkpoint_path).exists():

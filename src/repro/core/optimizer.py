@@ -2,8 +2,8 @@
 
 Mango's headline contribution is a scheduler-agnostic optimizer (paper
 §2.2/§2.4); this module makes that literal.  ``AskTellOptimizer`` owns *all*
-optimizer state — the parameter space, the strategy/GP, the RNG, and a trial
-ledger with stable ids — behind four calls:
+optimizer state — the parameter space, the strategy name, the RNG, and a
+trial ledger with stable ids — behind four calls:
 
     trials = opt.ask(n)          # propose n new configurations
     opt.tell(trial.id, value)    # observe a completed trial
@@ -16,9 +16,9 @@ thread/process pools, the Celery-style task queue, or a user's own system)
 can drive the same optimizer (the design Tune and Orchestrate argue for).
 
 Pending trials are first-class in the ledger: ``ask`` hands the full
-in-flight set to the strategy, and the default fused GP-BUCB path
-hallucinates them *inside* its jit'd ``lax.fori_loop`` — one device program
-per ask, no matter how many trials are outstanding.
+in-flight set to the bank's pipeline, which hallucinates them *inside* one
+device program (``gp.bank_absorb``) no matter how many trials are
+outstanding.
 
 Fault tolerance is the objective contract from the paper: trials that never
 come back are simply never told; ``tell_failed`` (or a non-finite ``tell``)
@@ -34,8 +34,7 @@ rows, raw y, status, completion order, counters) lives in a ``StudyLedger``
 — a registered pytree of fixed-capacity arrays — and an ``AskTellOptimizer``
 is a *view* into one ledger row.  Stand-alone construction makes a private
 bank of one; ``StudyBank`` passes a shared ledger so N studies checkpoint
-as one pytree and ask through one vmap'd device program.  The single-study
-compute path (what ``ask`` dispatches) is unchanged.
+as one pytree and ask through one vmap'd device program.
 """
 from __future__ import annotations
 
@@ -46,8 +45,8 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from repro.core import strategies
 from repro.core.spaces import ParamSpace
-from repro.core.strategies import STRATEGIES
 from repro.core.studybank import (S_FAILED, S_OBSERVED, S_PENDING,
                                   StudyLedger, _y_standardization,
                                   rng_from_state)
@@ -55,11 +54,6 @@ from repro.core.studybank import (S_FAILED, S_OBSERVED, S_PENDING,
 PENDING = "pending"
 OBSERVED = "observed"
 FAILED = "failed"
-
-# strategies whose asks are served by the bucketed StudyBank pipeline
-# (bank-of-one for stand-alone optimizers).  The legacy reference
-# strategies (hallucination_ref) and random keep their own propose paths.
-_BANKABLE = {"bayesian", "hallucination", "tpe", "clustering"}
 
 _STATUS_CODE = {PENDING: S_PENDING, OBSERVED: S_OBSERVED, FAILED: S_FAILED}
 _STATUS_NAME = {v: k for k, v in _STATUS_CODE.items()}
@@ -168,24 +162,21 @@ class AskTellOptimizer:
                  seed: int = 0, sign: float = 1.0,
                  domain_size: Optional[float] = None,
                  mc_samples: Optional[int] = None, fit_steps: int = 40,
-                 use_pallas: bool = False,
                  refit_every: int = 8,
                  strategy_kwargs: Optional[Dict[str, Any]] = None,
                  ledger: Optional[StudyLedger] = None,
                  study_index: int = 0):
         self.space = (param_space if isinstance(param_space, ParamSpace)
                       else ParamSpace(param_space))
-        if optimizer not in STRATEGIES:
-            raise ValueError(f"unknown optimizer {optimizer!r}; "
-                             f"choose from {sorted(STRATEGIES)}")
+        strategies.check_name(optimizer)
         self.optimizer = optimizer
         self.mc_samples = mc_samples
         self.fit_steps = fit_steps
-        self.use_pallas = use_pallas
         self.refit_every = refit_every
-        # strategy-specific knobs (e.g. tpe's gamma/pending_penalty,
-        # clustering's top_frac) forwarded verbatim to the constructor —
-        # unknown keys raise TypeError there, so typos can't be dropped
+        # strategy-specific knobs (tpe's gamma/pending_penalty,
+        # clustering's top_frac), checked at every ask
+        # (``strategies.check_kwargs``): unknown keys raise TypeError, so
+        # typos can't be dropped
         self.strategy_kwargs = dict(strategy_kwargs or {})
         self.domain_size = domain_size or self.space.domain_size
         self.sign = sign                   # +1 maximize, -1 minimize
@@ -203,8 +194,7 @@ class AskTellOptimizer:
             raise ValueError("ledger dim does not match the param space")
         self._trials: Dict[int, Trial] = {}   # insertion order == ask order
         self._best_trace: List[float] = []    # raw best-so-far snapshots
-        self._strat = None
-        self._gp_snapshot = None   # pending restore from load_state_dict
+        self._gp_snapshot = None   # a loaded fit schedule not yet asked on
         # the bank engine serving this view's asks: the owning StudyBank
         # (set by its constructor) or a lazily-built bank of one
         self._bank = None
@@ -251,11 +241,8 @@ class AskTellOptimizer:
         return [t for t in self._trials.values() if t.status == PENDING]
 
     def observed_trials(self) -> List[Trial]:
-        """Observed trials in *completion* order.  Async completions land
-        out of ask order; keeping the GP history in tell order makes it
-        append-only, so ``GaussianProcess.observe``'s prefix check stays
-        satisfied and observations extend the Cholesky incrementally
-        instead of tripping a full refit on almost every ask."""
+        """Observed trials in *completion* order (the order the bank's
+        gather and fit schedule see them)."""
         obs = [t for t in self._trials.values() if t.status == OBSERVED]
         obs.sort(key=lambda t: t.obs_seq)
         return obs
@@ -272,29 +259,7 @@ class AskTellOptimizer:
     def n_failed(self) -> int:
         return self._n_failed
 
-    # ----------------------------------------------------------- strategy
-    def _ensure_strategy(self):
-        if self._strat is None:
-            cls = STRATEGIES[self.optimizer]
-            self._strat = cls(self.space.dim, self.domain_size,
-                              fit_steps=self.fit_steps,
-                              use_pallas=self.use_pallas,
-                              refit_every=self.refit_every,
-                              **self.strategy_kwargs)
-            if self.optimizer not in _BANKABLE:
-                # legacy strategies replay their GP from the snapshot; the
-                # bank-served paths restored theirs into the ledger at
-                # load_state_dict time (the strategy GP stays untouched)
-                gp = getattr(self._strat, "gp", None)
-                if gp is not None and self._gp_snapshot is not None:
-                    obs = self.observed_trials()
-                    if obs:
-                        gp.restore_exact(
-                            self.space.encode([t.params for t in obs]),
-                            self._signed_y(obs), self._gp_snapshot)
-                self._gp_snapshot = None
-        return self._strat
-
+    # ------------------------------------------------------------- engine
     def _engine(self):
         """The StudyBank serving this view's asks — the owning bank when
         this view is a fleet member, else a lazily-built bank of one over
@@ -305,6 +270,10 @@ class AskTellOptimizer:
             self._bank = StudyBank._wrap_view(self)
         return self._bank
 
+    def _check_kwargs(self) -> None:
+        strategies.check_kwargs(self.optimizer, self.strategy_kwargs,
+                                self.space.dim, self.domain_size)
+
     def _signed_y(self, obs: List[Trial]) -> np.ndarray:
         return np.asarray([self.sign * t.value for t in obs],
                           dtype=np.float32)
@@ -314,41 +283,27 @@ class AskTellOptimizer:
         """Propose ``n`` new trials; they enter the ledger as pending."""
         if n < 1:
             raise ValueError("ask(n) requires n >= 1")
-        strat = self._ensure_strategy()
-        obs = self.observed_trials()
-        seed = self._ask_count
-        if not strat.needs_gp:
+        self._check_kwargs()
+        self._gp_snapshot = None   # the ledger holds the fit schedule now
+        if strategies.STRATEGIES[self.optimizer] == "random":
             n_mc = self.mc_samples or self.space.mc_samples(n)
             cands = self.space.sample(n_mc, self._rng)
-            idx = strat.propose(None, [], self.space.encode(cands), n,
-                                seed=seed)
+            idx = strategies.random_picks(len(cands), n, self._ask_count)
             chosen = [cands[i] for i in idx]
-        elif len(obs) < 2:
+        elif len(self.observed_trials()) < 2:
             # not enough observations to model: explore at random (the
             # drivers' initial_random phase lands here too)
             chosen = self.space.sample(n, self._rng)
-        elif self.optimizer in _BANKABLE:
-            # bank-of-one: the bucketed StudyBank pipeline serves the ask
-            # (zero retraces across observation growth).  Candidates come
-            # from this view's own RNG via the columnar sampler, which
-            # consumes the exact byte stream ``sample`` would — proposals
-            # are bit-identical to the retired per-strategy fused path.
+        else:
+            # the bucketed StudyBank pipeline serves the ask (zero
+            # retraces across observation growth).  Candidates come from
+            # this view's own RNG via the columnar sampler, which consumes
+            # the exact byte stream ``sample`` would.
             n_mc = self.mc_samples or self.space.mc_samples(n)
             cols = self.space.sample_columns(n_mc, self._rng)
             cfgs, enc = self._engine().ask_view(self, n, cols, n_mc)
             self._ask_count += 1
             return self._register_asked(list(cfgs), enc)
-        else:
-            n_mc = self.mc_samples or self.space.mc_samples(n)
-            cands = self.space.sample(n_mc, self._rng)
-            C = self.space.encode(cands)
-            X = self.space.encode([t.params for t in obs])
-            y = self._signed_y(obs)
-            pend = self.pending_trials()
-            P = (self.space.encode([t.params for t in pend])
-                 if pend else None)
-            idx = strat.propose(X, y, C, n, seed=seed, pending=P)
-            chosen = [cands[i] for i in idx]
         self._ask_count += 1
         return self._register_asked(chosen)
 
@@ -496,15 +451,10 @@ class AskTellOptimizer:
 
     # --------------------------------------------------------- state dict
     def _gp_export(self) -> Optional[Dict[str, Any]]:
-        """Fit-schedule snapshot for the state dict's ``"gp"`` key, in the
-        v1 ``GaussianProcess.export_state`` format: the live strategy GP
-        when it has one (legacy propose paths), else the ledger row's bank
-        fit schedule (the bank-served paths), else whatever snapshot a
-        load handed us that hasn't been consumed yet."""
-        gp = getattr(self._strat, "gp", None) if self._strat else None
-        snap = gp.export_state() if gp is not None else None
-        if snap is not None:
-            return snap
+        """Fit-schedule snapshot for the state dict's ``"gp"`` key (v1
+        format: the last fit's observation count and raw log-hypers): the
+        ledger row's bank fit schedule, else whatever snapshot a load
+        handed us that no ask has consumed yet."""
         led, b = self._led, self._b
         if int(led.have_fit[b]):
             return {
@@ -563,10 +513,9 @@ class AskTellOptimizer:
              if t.obs_seq is not None), default=-1)
         self._rng = rng_from_state(sd["rng_state"])
         self._gp_snapshot = sd.get("gp")
-        self._strat = None   # rebuilt (with GP replay) on the next ask
         snap = self._gp_snapshot
-        if snap and self.optimizer in _BANKABLE:
-            # bank-served paths keep their fit schedule in the ledger:
+        if snap and strategies.STRATEGIES[self.optimizer] != "random":
+            # the bank keeps the fit schedule in the ledger:
             # restore the log-hypers and the frozen standardization over
             # the first n_fit observations (the exact scalars the
             # uninterrupted run froze at its last refit), so the resumed

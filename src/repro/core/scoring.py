@@ -1,12 +1,9 @@
 """Conditioning-hardened device-resident GP posterior-scoring core.
 
-The single scoring backend behind every GP strategy (ISSUE 5): the fused
-GP-BUCB Pallas path (``gp.fused_propose_pallas[_pending]``) and the fused
-clustering pipeline (``acquisition.fused_cluster_propose``) both score
-candidates, absorb pending trials, and extend the system through the
-functions in this module — one implementation of the posterior math, with
-``use_pallas`` only toggling whether the scoring pass executes as the
-``kernels/gp_acquisition`` Pallas kernels or as their pure-jnp oracle twin.
+The posterior math behind both GP pick heads (``gp.bank_pick`` and
+``gp.bank_cluster_pick``) and the pending-trial absorb (``gp.bank_absorb``):
+the Matern kernel, the factor appends, the rank-1 variance downdate and
+the GP-BUCB slot loop, each written once.
 
 Why the old K⁻¹ path flipped picks (the ROADMAP PR-3 follow-up this module
 fixes): on near-noiseless objectives the fitted noise collapses, K becomes
@@ -30,14 +27,14 @@ Hardening, at the source:
     whole matrix every append, compounding error across batch slots;
   * the Schur solves accumulate in float64 when the backend has x64
     enabled, and otherwise apply one step of iterative refinement in
-    float32 (``harden=True``, the default);
+    float32;
   * the Schur complement is computed as ``c − Σl²`` (sum of positives, the
     Cholesky pivot formula) instead of ``c − k·u``, and floors are
     *relative* to the signal scale and shared bit-for-bit with the
     Cholesky path (``scoring.jitter`` / ``scoring.schur_floor``), so a
     binding floor can never split the two paths;
-  * ``cond_proxy_from_chol`` surfaces a condition-number diagnostic to the
-    host (strategies expose it as ``last_cond_proxy``).
+  * ``cond_estimate`` gives a condition-number estimate of K, which the
+    bank's factor stage counts against ``COND_PROXY_WARN``.
 """
 from __future__ import annotations
 
@@ -46,12 +43,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.gp_acquisition.ref import (matern52, score_cov_ref,
-                                              var_downdate_ref)
-
-# condition proxy above which float32 posterior scoring is presumed
-# unreliable (cond * eps_f32 ~ 1): strategies surface the proxy and docs
-# point users at raising the noise floor / enabling x64 beyond it
+# condition estimate above which float32 posterior scoring is presumed
+# unreliable (cond * eps_f32 ~ 1): the bank counts and warns past it, and
+# docs point users at raising the noise floor / enabling x64 beyond it
 COND_PROXY_WARN = 1e7
 
 JITTER = 1e-6
@@ -60,9 +54,9 @@ JITTER = 1e-6
 def jitter(var) -> jax.Array:
     """Diagonal jitter floor, *relative* to the signal variance (1e-6
     absolute or 1e-6·var, whichever is larger).  One definition shared by
-    the Cholesky path (``gp._masked_kernel``/``chol_append``) and the
-    hardened factor appends — a floor that binds on one path but not the
-    other would itself flip near-ties."""
+    the Cholesky factor (``gp._masked_kernel``) and the hardened factor
+    appends — a floor that binds on one path but not the other would
+    itself flip near-ties."""
     return JITTER * jnp.maximum(jnp.asarray(var, jnp.float32), 1.0)
 
 
@@ -80,8 +74,26 @@ def compute_dtype():
     return jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
 
 
+def matern52(x1, x2, ls, var):
+    """Matern-5/2 ARD kernel: x1 (n, d), x2 (m, d), ls (d,) -> (n, m).
+    The polynomial uses the raw squared distance; the clamp lives only
+    under the square root."""
+    z1 = x1 / ls
+    z2 = x2 / ls
+    d2 = (jnp.sum(z1 * z1, -1)[:, None] + jnp.sum(z2 * z2, -1)[None, :]
+          - 2.0 * z1 @ z2.T)
+    r = jnp.sqrt(jnp.maximum(d2, 1e-12))
+    s = jnp.sqrt(5.0) * r
+    return var * (1.0 + s + (5.0 / 3.0) * d2) * jnp.exp(-s)
+
+
 def adaptive_beta_dev(t: jax.Array, domain_size: jax.Array) -> jax.Array:
-    """jnp twin of ``acquisition.adaptive_beta`` (delta=0.1), trace-safe."""
+    """Mango's UCB exploration weight, trace-safe: the GP-UCB schedule
+    (Srinivas et al.) scaled, as the paper describes, by search-space size
+    and completed evaluations, where ``t`` counts the batch slot too
+    (GP-BUCB advances t per hallucinated pick):
+    ``beta_t = 2 log(domain_size t² π² / (6 δ))``, δ = 0.1, clipped to
+    [1, 100]."""
     t = jnp.maximum(t.astype(jnp.float32), 1.0)
     beta = 2.0 * jnp.log(jnp.maximum(domain_size, 2.0) * t * t
                          * (jnp.pi ** 2) / 0.6)
@@ -95,25 +107,12 @@ def linv_from_chol(L: jax.Array) -> jax.Array:
         L, jnp.eye(L.shape[0], dtype=L.dtype), lower=True)
 
 
-@jax.jit
-def cond_proxy_from_chol(L: jax.Array, mask: jax.Array) -> jax.Array:
-    """Cheap 2-norm condition proxy of K from its Cholesky diagonal:
-    ``cond₂(K) >= (max diag L / min diag L)²`` on the active block.  A
-    lower bound, not an estimate — but it tracks exactly the collapse mode
-    that loses float32 picks (fitted noise → 0 → tiny pivots)."""
-    d = jnp.abs(jnp.diagonal(L))
-    act = mask > 0
-    dmax = jnp.max(jnp.where(act, d, 0.0))
-    dmin = jnp.min(jnp.where(act, d, jnp.inf))
-    return (dmax / jnp.maximum(dmin, 1e-30)) ** 2
-
-
 @functools.partial(jax.jit, static_argnames=("iters",))
 def cond_estimate(L: jax.Array, mask: jax.Array, iters: int = 16) -> jax.Array:
     """Power-iteration estimate of cond₂(K) from its masked Cholesky factor.
 
-    ``cond_proxy_from_chol`` is a diagonal lower bound that runs 20-50x low
-    on correlated kernels; this estimate runs ``iters`` power-iteration
+    The diagonal bound ``(max diag L / min diag L)²`` runs 20-50x low on
+    correlated kernels; this estimate runs ``iters`` power-iteration
     steps for λmax(K) (via ``K v = L (Lᵀ v)``) and λmax(K⁻¹) (via two
     triangular solves) and multiplies the Rayleigh quotients, which lands
     within ~2x of ``np.linalg.cond`` on the repro surface.  The masked
@@ -144,23 +143,11 @@ def cond_estimate(L: jax.Array, mask: jax.Array, iters: int = 16) -> jax.Array:
     return jnp.maximum(rayleigh(k_mv) * rayleigh(kinv_mv), 1.0)
 
 
-def prescale(X, C, ls, block_s):
-    """Zero-pad d to a lane multiple and S to a block multiple, pre-divided
-    by the ARD lengthscales (padded columns contribute 0 to distances)."""
-    n, d = X.shape
-    S = C.shape[0]
-    dp = max(8, -(-d // 8) * 8)
-    Sp = -(-S // block_s) * block_s
-    Xs = jnp.zeros((n, dp), jnp.float32).at[:, :d].set(X / ls)
-    Cs = jnp.zeros((Sp, dp), jnp.float32).at[:S, :d].set(C / ls)
-    return Xs, Cs
-
-
 # --------------------------------------------------------------------------- #
 # Hardened rank-1 factor extension (the fixed Schur append)
 # --------------------------------------------------------------------------- #
 def factor_append(L: jax.Array, Linv: jax.Array, idx: jax.Array,
-                  k_vec: jax.Array, var, noise, harden: bool = True):
+                  k_vec: jax.Array, var, noise):
     """Extend (L, Linv) by the point whose masked Matern column is k_vec.
 
     Returns ``(L', Linv', u, schur)`` where ``u = K⁻¹k`` is the Schur
@@ -169,7 +156,7 @@ def factor_append(L: jax.Array, Linv: jax.Array, idx: jax.Array,
     block-inverse algebra as the old K⁻¹ extension, but written into one
     fresh row instead of rewriting the whole inverse.
 
-    Conditioning (``harden=True``): the two triangular solves run as Linv
+    Conditioning: the two triangular solves run as Linv
     matvecs accumulated in float64 when x64 is enabled; on float32-only
     backends each gets one step of iterative refinement (residual against
     L, corrected through Linv).  The Schur complement uses the Cholesky
@@ -185,10 +172,10 @@ def factor_append(L: jax.Array, Linv: jax.Array, idx: jax.Array,
     # contracts over M's leading axis in place instead of materializing an
     # (n, n) transpose per op, which dominated the append cost at n=1024
     l_vec = Li @ kc                       # forward solve L l = k
-    if harden and not f64:
+    if not f64:
         l_vec = l_vec + Li @ (kc - Lc @ l_vec)
     u = l_vec @ Li                        # back solve  Lᵀ u = l
-    if harden and not f64:
+    if not f64:
         u = u + (l_vec - u @ Lc) @ Li
     c = (var + noise + jitter(var)).astype(dt)
     active = jnp.arange(n) < idx
@@ -211,42 +198,19 @@ def kinv_matvec(Linv: jax.Array, v: jax.Array) -> jax.Array:
     return (Linv @ v) @ Linv
 
 
-# --------------------------------------------------------------------------- #
-# The one scoring entry point (Pallas kernel or jnp twin — same math)
-# --------------------------------------------------------------------------- #
-def posterior_scores(Cs: jax.Array, Xs: jax.Array, y: jax.Array,
-                     mask: jax.Array, Linv: jax.Array, var, noise, *,
-                     use_pallas: bool, block_s: int = 256):
-    """(mu, sig2, Kc, alpha) for pre-scaled candidates Cs against the
-    pre-scaled training set (Xs, mask) through the factor Linv.
+def var_downdate(Cs, x_star, Kc, u, schur, sig2, var):
+    """Rank-1 variance downdate after absorbing x*.
 
-    Every GP strategy's device program scores through this function — the
-    fused GP-BUCB slot loop and the clustering pipeline alike (the "one
-    scoring backend" contract; tests monkeypatch it to verify dispatch).
+    With the absorbed point's Schur vector u = K⁻¹k_* and complement
+    ``schur``, each candidate's posterior variance contracts by
+    ``(k(c, x*) − k_cᵀu)² / schur`` — exactly the extended system's
+    block-inverse quadratic form, at O(n) per candidate.  ``Cs`` and
+    ``x_star`` are pre-scaled by the lengthscales; returns the new
+    variances and the candidates' kernel column against x*.
     """
-    from repro.kernels.gp_acquisition.gp_acquisition import score_cov_pallas
-
-    alpha = kinv_matvec(Linv, y * mask)
-    if use_pallas:
-        mu, sig2, Kc = score_cov_pallas(Cs, Xs, mask, Linv, alpha, var,
-                                        noise, block_s=block_s)
-    else:
-        mu, sig2, Kc = score_cov_ref(Cs, Xs, mask, Linv, alpha,
-                                     jnp.float32(1.0), var, noise)
-    return mu, sig2, Kc, alpha
-
-
-def var_downdate(Cs, x_star, Kc, u, schur, sig2, var, *, use_pallas: bool,
-                 block_s: int = 256):
-    """Rank-1 variance downdate after absorbing x*: kernel or jnp twin."""
-    from repro.kernels.gp_acquisition.gp_acquisition import \
-        var_downdate_pallas
-
-    if use_pallas:
-        return var_downdate_pallas(Cs, x_star, Kc, u, schur, sig2, var,
-                                   block_s=block_s)
-    return var_downdate_ref(Cs, x_star, Kc, u, schur, sig2,
-                            jnp.float32(1.0), var)
+    knew = matern52(Cs, x_star[None, :], jnp.float32(1.0), var)[:, 0]
+    proj = knew - Kc @ u
+    return jnp.maximum(sig2 - proj * proj / schur, 1e-10), knew
 
 
 # --------------------------------------------------------------------------- #
@@ -258,11 +222,10 @@ def absorb_pending(Xs: jax.Array, y: jax.Array, mask: jax.Array,
                    pend_cap: int):
     """Hallucinate the (padded, ``pend_cap``) pending buffer in-program.
 
-    GP-BUCB semantics, identical to the host ``GaussianProcess.hallucinate``
-    loop: posterior mean at each in-flight point from the current extended
-    system, hardened rank-1 (L, Linv) append, phantom y at the mean.  Both
-    the fused Pallas proposal and the clustering pipeline absorb through
-    this one loop.  ``Ps`` rows are pre-scaled like ``Xs``.
+    GP-BUCB semantics: posterior mean at each in-flight point from the
+    current extended system, hardened rank-1 (L, Linv) append, phantom y
+    at the mean.  Both pick heads absorb through this one loop
+    (``gp.bank_absorb``).  ``Ps`` rows are pre-scaled like ``Xs``.
     """
     def absorb(j, carry):
         def do(c):
@@ -283,48 +246,24 @@ def absorb_pending(Xs: jax.Array, y: jax.Array, mask: jax.Array,
 
 
 # --------------------------------------------------------------------------- #
-# Shared GP-BUCB pick loop (scoring pass + per-slot rank-1 downdates)
+# GP-BUCB pick loop (per-slot rank-1 downdates)
 # --------------------------------------------------------------------------- #
-def pick_downdate_loop(Cs: jax.Array, Xs: jax.Array, S: int, y: jax.Array,
-                       mask: jax.Array, L: jax.Array, Linv: jax.Array,
-                       var, noise, n_obs: jax.Array,
-                       domain_size: jax.Array, batch_size: int, *,
-                       use_pallas: bool, block_s: int = 256) -> jax.Array:
-    """GP-BUCB slot loop on the shared scorer with O(n S) per-slot rescores.
-
-    One ``posterior_scores`` pass scores every candidate *and* caches the
-    masked cross-covariance block k(C, X).  Hallucinating at the posterior
-    mean leaves the mean invariant, so per slot only the variance moves:
-    the rank-1 downdate contracts it by ``(k(c, x*) − k_cᵀu)²/schur`` from
-    the cached block — O(n S) — instead of re-running the O(n² S)
-    quadratic form per slot.  The cached block gains the picked point's
-    column each slot, so later downdates see the full extended system.
-    """
-    # module-attribute call: the "one scoring backend" dispatch test
-    # monkeypatches ``scoring.posterior_scores`` and must see this call
-    import repro.core.scoring as scoring
-
-    mu, sig2, Kc, _ = scoring.posterior_scores(
-        Cs, Xs, y, mask, Linv, var, noise, use_pallas=use_pallas,
-        block_s=block_s)
-    return pick_downdate_from_scores(
-        Cs, S, mu, sig2, Kc, L, Linv, var, noise, n_obs, domain_size,
-        batch_size, use_pallas=use_pallas, block_s=block_s)
-
-
 def pick_downdate_from_scores(Cs: jax.Array, S: int, mu: jax.Array,
                               sig2: jax.Array, Kc: jax.Array, L: jax.Array,
                               Linv: jax.Array, var, noise,
                               n_obs: jax.Array, domain_size: jax.Array,
-                              batch_size: int, *, use_pallas: bool,
-                              block_s: int = 256) -> jax.Array:
-    """The slot loop of ``pick_downdate_loop`` given an already-scored
-    candidate set — op-for-op the same program, split out so the staged
-    bank pipeline (``gp.bank_pick``) can feed scores whose Matern ``exp``
-    was evaluated in its own jit (XLA:CPU scalarizes ``exp`` whenever it
-    is fused with any producer; standalone it vectorizes)."""
-    import repro.core.scoring as scoring
+                              batch_size: int) -> jax.Array:
+    """GP-BUCB slot loop over an already-scored candidate set, with O(n S)
+    per-slot rescores.
 
+    ``gp.bank_scores`` scores every candidate *and* hands over the masked
+    cross-covariance block ``Kc = k(C, X)``.  Hallucinating at the
+    posterior mean leaves the mean invariant, so per slot only the
+    variance moves: the rank-1 downdate contracts it by
+    ``(k(c, x*) − k_cᵀu)²/schur`` from the cached block — O(n S) — instead
+    of re-running the O(n² S) quadratic form per slot.  The cached block
+    gains the picked point's column each slot, so later downdates see the
+    full extended system."""
     Sp = Cs.shape[0]
 
     def pick(b, sig2, avail, picks):
@@ -342,9 +281,7 @@ def pick_downdate_from_scores(Cs: jax.Array, S: int, mu: jax.Array,
         # (columns of not-yet-active slots are zero by construction)
         k_vec = Kc[idx]
         L, Linv, u, schur = factor_append(L, Linv, slot, k_vec, var, noise)
-        sig2, k_new = scoring.var_downdate(
-            Cs, Cs[idx], Kc, u, schur, sig2, var, use_pallas=use_pallas,
-            block_s=block_s)
+        sig2, k_new = var_downdate(Cs, Cs[idx], Kc, u, schur, sig2, var)
         Kc = Kc.at[:, slot].set(k_new)
         return L, Linv, Kc, sig2, avail, picks
 

@@ -51,6 +51,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.core import strategies
 from repro.tracing import Counters, span
 
 # trial-status codes (ledger ``status`` array; 0 = empty slot)
@@ -91,26 +92,12 @@ def row_buckets(R: int) -> List[int]:
     return sorted({row_bucket(k, R) for k in range(1, R + 1)})
 
 
-# strategy name -> dispatch family for the bank pipeline.  "gp" and
-# "cluster" share the staged obs-dependent stages (factors, prescale,
-# standardization) and differ only in the pick head; "tpe" has its own
-# buffer layout; "random"/"legacy" rows ask through their own view.
-_FAMILY = {
-    "bayesian": "gp",
-    "hallucination": "gp",
-    "clustering": "cluster",
-    "tpe": "tpe",
-    "random": "random",
-    "hallucination_ref": "legacy",
-}
-
-
 def _y_standardization(v: np.ndarray):
-    """Frozen-standardization scalars over a signed f32 history, with the
-    exact op sequence of ``GaussianProcess.fit``: f32 numpy mean (exact
-    f32 round-trip) and ``float(v.std()) + 1e-6`` (f64 add, rounded to f32
-    at the consuming op).  Used by the bank fit schedule AND v1-checkpoint
-    restore so a resumed run standardizes bit-identically."""
+    """Frozen-standardization scalars over a signed f32 history: f32 numpy
+    mean (exact f32 round-trip) and ``float(v.std()) + 1e-6`` (f64 add,
+    rounded to f32 at the consuming op).  Used by the bank fit schedule
+    AND v1-checkpoint restore so a resumed run standardizes
+    bit-identically."""
     v = np.asarray(v, np.float32)
     if not len(v):
         return np.float32(0.0), np.float32(1.0)
@@ -300,7 +287,6 @@ class StudyBank:
                  optimizer=None, seed: int = 0,
                  sign: float = 1.0, domain_size: Optional[float] = None,
                  mc_samples: Optional[int] = None, fit_steps: int = 40,
-                 use_pallas: bool = False,
                  refit_every: int = 8,
                  strategy_kwargs: Optional[Dict[str, Any]] = None):
         from repro.core.optimizer import AskTellOptimizer
@@ -320,7 +306,6 @@ class StudyBank:
         self.optimizer = (names[0] if len(set(names)) == 1 else "mixed")
         self.mc_samples = mc_samples
         self.fit_steps = fit_steps
-        self.use_pallas = use_pallas
         self.refit_every = refit_every
         self.strategy_kwargs = dict(strategy_kwargs or {})
         self.seed = seed
@@ -340,8 +325,7 @@ class StudyBank:
             AskTellOptimizer(self.space, optimizer=names[i],
                              seed=seed + 1 + i, sign=sign,
                              domain_size=domain_size, mc_samples=mc_samples,
-                             fit_steps=fit_steps, use_pallas=use_pallas,
-                             refit_every=refit_every,
+                             fit_steps=fit_steps, refit_every=refit_every,
                              strategy_kwargs=strategy_kwargs,
                              ledger=self.ledger, study_index=i)
             for i in range(n_studies)]
@@ -362,7 +346,6 @@ class StudyBank:
         bank.optimizer = view.optimizer
         bank.mc_samples = view.mc_samples
         bank.fit_steps = view.fit_steps
-        bank.use_pallas = view.use_pallas
         bank.refit_every = view.refit_every
         bank.strategy_kwargs = dict(view.strategy_kwargs)
         bank.seed = None
@@ -382,7 +365,7 @@ class StudyBank:
     def _rebuild_groups(self) -> None:
         """Recompute the strategy-family routing tables (and drop the
         device cache, whose row layout depends on them)."""
-        fams = {b: _FAMILY.get(v.optimizer, "legacy")
+        fams = {b: strategies.STRATEGIES[v.optimizer]
                 for b, v in self._members.items()}
         self._fams = fams
         gpr = sorted(b for b, f in fams.items() if f in ("gp", "cluster"))
@@ -390,7 +373,7 @@ class StudyBank:
         self._gp_pos = {int(r): i for i, r in enumerate(gpr)}
         bankable = np.zeros(self.ledger.n_studies, bool)
         for b, f in fams.items():
-            bankable[b] = f in ("gp", "cluster", "tpe")
+            bankable[b] = f != "random"
         self._bankable = bankable
         self._gp_cache = None
 
@@ -398,15 +381,11 @@ class StudyBank:
         """Switch study ``b``'s strategy (per-study data, not bank code
         paths).  Counters/observations are untouched; the next ask routes
         through the new family's pick head."""
-        from repro.core.strategies import STRATEGIES
-        if name not in STRATEGIES:
-            raise ValueError(f"unknown optimizer {name!r}; "
-                             f"choose from {sorted(STRATEGIES)}")
+        strategies.check_name(name)
         b = int(b)
         v = self.studies[b]
         if v.optimizer != name:
             v.optimizer = name
-            v._strat = None
             self.strategy_names[b] = name
         self.optimizer = (self.strategy_names[0]
                           if len(set(self.strategy_names)) == 1
@@ -447,12 +426,9 @@ class StudyBank:
         if kind == "create":
             float(op.get("sign", 1.0))
             nm = op.get("optimizer")
-            if nm is not None:
-                from repro.core.strategies import STRATEGIES
-                if nm not in STRATEGIES:
-                    raise ValueError(
-                        f"unknown optimizer {nm!r}; choose from "
-                        f"{sorted(STRATEGIES)}")
+            strategies.check_kwargs(view.optimizer if nm is None else nm,
+                                    view.strategy_kwargs, view.space.dim,
+                                    view.domain_size)
         elif kind == "ask":
             if int(op["n"]) < 1:
                 raise ValueError("ask(n) requires n >= 1")
@@ -536,15 +512,17 @@ class StudyBank:
     def ask_all(self, n: int = 1) -> List[list]:
         """Propose ``n`` new trials for every study.
 
-        Studies still in the random phase (< 2 observations) or whose
-        strategy has no bank family (random / reference strategies) ask
-        through their own view; every other study is gathered into one
+        Studies still in the random phase (< 2 observations) or running
+        the ``random`` strategy ask through their own view; every other
+        study is gathered into one
         shape-bucketed device batch and served by the staged pipeline,
         sub-batched per strategy family.  Returns
         ``[trials_of_study_0, ...]``.
         """
         if n < 1:
             raise ValueError("ask_all(n) requires n >= 1")
+        for v in self.studies:
+            v._check_kwargs()
         led = self.ledger
         B = led.n_studies
         n_obs = led.n_observed()
@@ -713,18 +691,18 @@ class StudyBank:
         the first due row, whose results are dropped; write-back touches
         the due rows alone, so non-due studies' frozen hypers (and frozen
         y standardization) stay bit-stable.  Standardization scalars are
-        computed on the host with the exact single-study op sequence
-        (``_y_standardization``), so a study served by the bank
-        standardizes bit-identically to the pre-refactor engine.
+        computed on the host (``_y_standardization``, which a checkpoint
+        restore also runs), so a resumed study standardizes
+        bit-identically.
         Returns True when anything refit (obs stamp was bumped)."""
         led = self.ledger
         ko64 = ko.astype(np.int64)
         due = ((led.have_fit[rows] == 0) |
                (ko64 - led.n_fit[rows] >= self.refit_every))
-        # frozen-standardization sanity (the ``GaussianProcess.observe``
-        # guard): a degenerate fit (y_std ~ 1e-6 from constant initial
-        # observations) would blow incoming values up to ~1e6 standardized
-        # and wreck the acquisition surface for up to refit_every asks —
+        # frozen-standardization sanity: a degenerate fit (y_std ~ 1e-6
+        # from constant initial observations) would blow incoming values
+        # up to ~1e6 standardized and wreck the acquisition surface for up
+        # to refit_every asks —
         # re-tune immediately instead.  Checked over everything observed
         # since the last fit so replay reaches the same decision.
         for i, r in enumerate(rows):
@@ -826,7 +804,7 @@ class StudyBank:
         with span("mango.gather"):
             Xd, yraw, mask = self._gather_obs(ko, na, gpr)
         self._fit_if_due(Xd, yraw, mask, ko, gpr)   # a refit bumps the stamp
-        # frozen standardization, exactly the single-study GP contract
+        # frozen standardization
         z = (yraw - led.y_mean[gpr][:, None]) / led.y_std[gpr][:, None]
         z = (z * mask).astype(np.float32)
         ls = np.exp(led.log_ls[gpr]).astype(np.float32)
@@ -916,9 +894,8 @@ class StudyBank:
         n_eff = (ko + kp).astype(np.float32)
         dom = np.float32(self._members[int(rows[0])].domain_size)
         if fam == "cluster":
-            from repro.core.strategies import n_top_candidates
             top_frac = self.strategy_kwargs.get("top_frac", 0.2)
-            n_top = n_top_candidates(C.shape[1], n, top_frac)
+            n_top = strategies.n_top_candidates(C.shape[1], n, top_frac)
             # one vmap'd seeding dispatch for the sub-batch (J101/J102:
             # a per-study PRNGKey loop is R device calls + R host reads)
             keys = jax.vmap(jax.random.PRNGKey)(
@@ -938,10 +915,9 @@ class StudyBank:
 
     def _dispatch_tpe(self, Xd, yraw, Pd, C, k_obs, k_pend, n, na):
         from repro.core import tpe as tpe_lib
-        from repro.kernels.tpe_kde.ops import pad_dims
         d = self.ledger.dim
         R = Xd.shape[0]
-        dp = pad_dims(d)
+        dp = tpe_lib.pad_dims(d)
         # TPE layout: observed rows, then pending rows, then zeros
         Xt = np.zeros((R, na, dp), np.float32)
         yt = np.zeros((R, na), np.float32)
@@ -963,8 +939,7 @@ class StudyBank:
                          np.full((R,), Sp, np.float32),
                          np.full((R,), gamma, np.float32)], axis=1)
         return tpe_lib.fused_tpe_propose_bank(
-            Xt, yt, Ct, meta, batch_size=n, d_true=d,
-            use_pallas=False)
+            Xt, yt, Ct, meta, batch_size=n, d_true=d)
 
     # ---------------------------------------------------------- checkpoint
     def state_dict(self) -> Dict[str, Any]:
@@ -979,9 +954,9 @@ class StudyBank:
             "rng_state": self._rng.bit_generator.state,
             "strategies": list(self.strategy_names),
             "studies": [v.state_dict() for v in self.studies],
-            # the bank fit schedule lives in the ledger, not the views'
-            # strategy GPs — carried bank-level so the per-study entries
-            # stay exactly the v1 single-study format
+            # the bank fit schedule lives in the ledger — carried
+            # bank-level so the per-study entries stay exactly the v1
+            # single-study format
             "gp_bank": [{
                 "log_ls": [float(x) for x in led.log_ls[b]],
                 "log_var": float(led.log_var[b]),
@@ -1104,7 +1079,6 @@ class StudyBank:
             v.sign = ms["sign"]
             v._best_trace = list(ms["best_trace"])
             v._gp_snapshot = ms["gp"]
-            v._strat = None
             v._rng = unpack_rng_state(led.rng_state[b])
             v._trials = {
                 tid: Trial(tid, dict(params), _ledger=led, _study=b)

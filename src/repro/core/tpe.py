@@ -16,13 +16,12 @@ the comparison:
     no information-gain machinery, which is exactly the gap Mango's
     hallucination/clustering strategies target).
 
-As of ISSUE 4 the whole proposal is ONE jit'd device program per ask
-(``fused_tpe_propose``, mirroring ``gp.fused_propose_pallas_pending``): the
-good/bad split runs as masked ranks over the padded observation buffer, the
-O(m n d) product-Parzen scorer is either the pure-jnp oracle or the
-``kernels/tpe_kde`` Pallas kernel (``use_pallas=True``), and the batch is
-selected with ``lax.top_k`` — only the (batch_size,) pick indices leave the
-device.  The seed numpy loop is kept as ``propose_host``, the parity oracle.
+The whole proposal is ONE jit'd device program per ask
+(``fused_tpe_propose``, vmapped over the bank's studies by
+``fused_tpe_propose_bank``): the good/bad split runs as masked ranks over
+the padded observation buffer, the O(m n d) product-Parzen scorer is
+``tpe_scores``, and the batch is selected with ``lax.top_k`` — only the
+(batch_size,) pick indices leave the device.
 
 Pending trials: Hyperopt's parallelism is *naive* — in-flight trials are
 ignored, so an async replacement pick degenerates to re-proposing the
@@ -34,30 +33,82 @@ absorb is just one extra membership mask over the same buffer — still one
 device program per ask, no matter how many trials are outstanding.
 
 Registered as ``optimizer="tpe"`` so every Tuner feature (schedulers, fault
-tolerance, checkpointing) applies to the baseline too.  ``propose`` is
-stateless — it never mutates strategy or shared buffers, so concurrent
-drivers can share one instance.
+tolerance, checkpointing) applies to the baseline too.
 """
 from __future__ import annotations
 
 import functools
-from typing import List
+import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-
-from repro.core.strategies import STRATEGIES, BaseStrategy
-from repro.kernels.tpe_kde.ops import pad_dims, pad_rows
-from repro.kernels.tpe_kde.ref import scott_bandwidth, tpe_scores_ref
-from repro.kernels.tpe_kde.tpe_kde import tpe_scores_pallas
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "batch_size", "d_true", "use_pallas", "block_s"))
-def fused_tpe_propose(X, y, C, meta, *, batch_size: int, d_true: int,
-                      use_pallas: bool = False,
-                      block_s: int = 256):
+def pad_dims(d: int) -> int:
+    """Lane-pad the encoded dim (>= 8, multiple of 8)."""
+    return max(8, int(math.ceil(d / 8)) * 8)
+
+
+def scott_bandwidth(n_pts, d_true: int):
+    """Scott-rule base bandwidth: scalar, count- and dim-dependent only
+    (not data-dependent), floored away from zero."""
+    n = jnp.maximum(n_pts, 1.0)
+    return jnp.maximum(n ** (-1.0 / (d_true + 4)), 1e-2) * 0.5 + 1e-3
+
+
+_MAX_ELEMS = 4_000_000   # (block, n, 2d) temporary cap (16 MB f32)
+
+
+def tpe_scores(cands, pts, a, wg, wb, scal, *, d_true: int):
+    """l(x)/g(x) log-ratio for every candidate.
+
+    ``a`` (n, dp) is the per-row per-DIM ``1/(2 bw_j^2)`` scale — with
+    gamma <= 0.5 every observation belongs to exactly one split, so each
+    row carries its own split's bandwidth vector and ONE exp per
+    (candidate, row, dim) covers both densities (a two-mask formulation
+    pays exactly double).  Per-dim bandwidths (Scott base scaled by each dim's
+    split spread) sharpen low-variance dims — categorical one-hot columns
+    especially, whose 0/1 support a d-global bandwidth oversmooths.
+    ``wg``/``wb`` (n,) are the 0/1 split memberships and ``scal`` packs
+    [1/n_g, 1/n_b, 0, 0] as one (1, 4) row.
+
+    Shapes are static at trace time, so the streaming decision is free:
+    problems whose (S, n, d) temporary fits ``_MAX_ELEMS`` score in one
+    block (no ``lax.map`` per-chunk overhead — it costs real latency at
+    small sizes); larger ones stream candidates through the biggest
+    256-multiple chunk that both fits the cap and divides S, so the
+    temporary stays ~16 MB at any mc_samples.
+    """
+    S = cands.shape[0]
+    n = pts.shape[0]
+    Xd = pts[:, :d_true]
+
+    def score_block(cb):
+        d2 = (cb[:, None, :d_true] - Xd[None, :, :]) ** 2     # (b, n, d)
+        E = jnp.exp(-d2 * a[None, :, :d_true])                # (b, n, d)
+        densg = jnp.einsum("snd,n->sd", E, wg) * scal[0, 0] + 1e-12
+        densb = jnp.einsum("snd,n->sd", E, wb) * scal[0, 1] + 1e-12
+        return jnp.sum(jnp.log(densg) - jnp.log(densb), axis=-1)
+
+    nd = n * d_true
+    if S * nd <= _MAX_ELEMS:
+        return score_block(cands)
+    block = min(S, max(256, _MAX_ELEMS // nd // 256 * 256))
+    while block > 256 and S % block:
+        block -= 256
+    if S % block:
+        # a non-256-multiple S: zero-pad up to the block grid (padded rows
+        # score garbage, sliced off below) so the temporary cap holds for
+        # ANY candidate count
+        Sp = -(-S // block) * block
+        cands = jnp.pad(cands, ((0, Sp - S), (0, 0)))
+    Sp = cands.shape[0]
+    out = jax.lax.map(score_block, cands.reshape(Sp // block, block, -1))
+    return out.reshape(Sp)[:S]
+
+
+@functools.partial(jax.jit, static_argnames=("batch_size", "d_true"))
+def fused_tpe_propose(X, y, C, meta, *, batch_size: int, d_true: int):
     """One device program per ask: split -> l/g scoring -> ``lax.top_k``.
 
     X (na, dp) is the padded buffer of observed rows followed by pending
@@ -80,7 +131,8 @@ def fused_tpe_propose(X, y, C, meta, *, batch_size: int, d_true: int,
     neg = jnp.where(is_obs, -y, jnp.inf)
     order = jnp.argsort(neg)
     rank = jnp.zeros_like(row).at[order].set(row)
-    # split count in float32 on BOTH paths so ceil ties can't flip vs host
+    # split count in float32, like the float64 reference's, so ceil ties
+    # can't flip against it
     n_good = jnp.maximum(
         1, jnp.ceil(gamma * n_obs.astype(jnp.float32))).astype(jnp.int32)
     good = (rank < n_good) & is_obs
@@ -110,21 +162,14 @@ def fused_tpe_propose(X, y, C, meta, *, batch_size: int, d_true: int,
                   (0.5 / (bw_b * bw_b))[None, :]))
     scal = jnp.stack([1.0 / ng, 1.0 / nb, jnp.float32(0.0),
                       jnp.float32(0.0)])[None, :]
-    if use_pallas:
-        score = tpe_scores_pallas(C, X, a, wg, wb, scal, d_true=d_true,
-                                  block_s=block_s)
-    else:
-        score = tpe_scores_ref(C, X, a, wg, wb, scal, d_true=d_true)
+    score = tpe_scores(C, X, a, wg, wb, scal, d_true=d_true)
     score = jnp.where(jnp.arange(C.shape[0]) < n_cand, score, -jnp.inf)
     _, idx = jax.lax.top_k(score, batch_size)
     return idx
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "batch_size", "d_true", "use_pallas", "block_s"))
-def fused_tpe_propose_bank(X, y, C, meta, *, batch_size: int, d_true: int,
-                           use_pallas: bool = False,
-                           block_s: int = 256):
+@functools.partial(jax.jit, static_argnames=("batch_size", "d_true"))
+def fused_tpe_propose_bank(X, y, C, meta, *, batch_size: int, d_true: int):
     """``fused_tpe_propose`` vmapped over a leading study axis (the
     StudyBank ask path): X (B, na, dp), y (B, na), C (B, Sp, dp) and one
     packed meta row per study.  The per-study masked ranks come from
@@ -132,143 +177,5 @@ def fused_tpe_propose_bank(X, y, C, meta, *, batch_size: int, d_true: int,
     how many observations each study holds.  Returns (B, batch_size) pick
     indices."""
     one = functools.partial(fused_tpe_propose, batch_size=batch_size,
-                            d_true=d_true, use_pallas=use_pallas,
-                            block_s=block_s)
+                            d_true=d_true)
     return jax.vmap(one)(X, y, C, meta)
-
-
-class TPEStrategy(BaseStrategy):
-    needs_gp = True  # needs observations (not an actual GP)
-
-    def __init__(self, dim: int, domain_size: float, gamma: float = 0.25,
-                 pending_penalty: bool = False, fit_steps: int = 40,
-                 use_pallas: bool = False,
-                 refit_every: int = 8):
-        # fit_steps/refit_every belong to the standard strategy-constructor
-        # contract; TPE has no GP to apply them to, so they are accepted and
-        # unused.  Anything else is a typo -> TypeError, like the other
-        # strategies.
-        if dim < 1:
-            raise ValueError(f"TPE needs dim >= 1, got {dim}")
-        # gamma is the GOOD quantile; > 0.5 would make the "good" model the
-        # majority (nonsensical for TPE) and is what lets the fused program
-        # score both splits with one exp per row (disjoint splits)
-        if not 0.0 < gamma <= 0.5:
-            raise ValueError(f"gamma must be in (0, 0.5], got {gamma}")
-        if not domain_size > 0:
-            raise ValueError(f"domain_size must be > 0, got {domain_size}")
-        self.dim = int(dim)
-        self.domain_size = float(domain_size)
-        self.gamma = float(gamma)
-        self.pending_penalty = bool(pending_penalty)
-        self.use_pallas = bool(use_pallas)
-
-    # ------------------------------------------------------------ host oracle
-    def _split_count(self, n: int) -> int:
-        """Good-split size, computed in float32 like the device program."""
-        return max(1, int(np.ceil(np.float32(self.gamma) * np.float32(n))))
-
-    @staticmethod
-    def _scott_bw(n_pts: int, d: int) -> np.float32:
-        """Scott-rule base bandwidth, computed in float32 like the device."""
-        return max(np.float32(max(n_pts, 1)) ** np.float32(-1.0 / (d + 4)),
-                   np.float32(1e-2)) * np.float32(0.5) + np.float32(1e-3)
-
-    @staticmethod
-    def _dim_scale(pts: np.ndarray) -> np.ndarray:
-        """Per-dim bandwidth scale clip(2*std_j, 0.1, 1.0) in f32 — the
-        host twin of the device's masked-moment computation."""
-        p = np.asarray(pts, np.float32)
-        n = np.float32(max(len(p), 1))
-        mean = p.sum(axis=0, dtype=np.float32) / n
-        var = ((p - mean) ** 2).sum(axis=0, dtype=np.float32) / n
-        return np.clip(np.float32(2.0) * np.sqrt(var),
-                       np.float32(0.1), np.float32(1.0))
-
-    @staticmethod
-    def _kde_sum(pts: np.ndarray, x: np.ndarray, bw) -> np.ndarray:
-        """(m, d) per-dim SUM of Gaussian Parzen kernels of x under pts."""
-        inv2bw2 = np.float32(0.5) / np.float32(bw * bw)
-        d2 = (x[:, None, :] - pts[None, :, :]) ** 2     # (m, n, d)
-        return np.exp(-d2 * inv2bw2).sum(axis=1)
-
-    @classmethod
-    def _log_kde(cls, pts: np.ndarray, x: np.ndarray) -> np.ndarray:
-        """1D-product Parzen log-density of x (m, d) under pts (n, d)."""
-        n = max(len(pts), 1)
-        dens = cls._kde_sum(pts, x, cls._scott_bw(n, pts.shape[1])) / n
-        return np.log(dens + 1e-12).sum(axis=1)
-
-    def propose_host(self, X, y, candidates, batch_size, seed=0,
-                     pending=None) -> List[int]:
-        """The seed numpy pipeline, kept as the parity oracle for the fused
-        device program (same split, per-split bandwidths, tie-breaking).
-
-        Pending rows (when the penalty is on) join the bad mixture at the
-        bad split's bandwidth.  In the degenerate empty-bad case — only
-        reachable with a single observation, the optimizer never asks with
-        fewer than two — the good rows stand in for the bad split at their
-        own bandwidth (exactly the device program's per-row-scale
-        semantics)."""
-        y = np.asarray(y, dtype=float)
-        n = len(y)
-        d = np.asarray(X).shape[1]
-        n_good = self._split_count(n)
-        order = np.argsort(-y, kind="stable")  # maximization
-        Xa = np.asarray(X)
-        good = Xa[order[:n_good]]
-        bad = Xa[order[n_good:]]
-        pend = (np.asarray(pending, dtype=Xa.dtype)
-                if (self.pending_penalty and pending is not None
-                    and len(pending)) else Xa[:0])
-        ng = len(good)
-        nb = (len(bad) if len(bad) else ng) + len(pend)
-        bad_eff = bad if len(bad) else good
-        b_pts = (np.concatenate([bad_eff, pend]) if len(pend) else bad_eff)
-        bw_g = self._scott_bw(ng, d) * self._dim_scale(good)      # (d,)
-        bw_b = self._scott_bw(nb, d) * self._dim_scale(b_pts)
-        candidates = np.asarray(candidates)
-        batch_size = min(batch_size, len(candidates))
-        lg = np.log(self._kde_sum(good, candidates, bw_g) / ng
-                    + 1e-12).sum(axis=1)
-        bad_sum = (self._kde_sum(bad, candidates, bw_b) if len(bad)
-                   else self._kde_sum(good, candidates, bw_g))
-        if len(pend):
-            bad_sum = bad_sum + self._kde_sum(pend, candidates, bw_b)
-        lb = np.log(bad_sum / nb + 1e-12).sum(axis=1)
-        top = np.argsort(-(lg - lb), kind="stable")[:batch_size]
-        return [int(i) for i in top]
-
-    # --------------------------------------------------------- device program
-    def propose(self, X, y, candidates, batch_size, seed=0,
-                pending=None) -> List[int]:
-        X = np.asarray(X, dtype=np.float32)
-        y = np.asarray(y, dtype=np.float32)
-        C = np.ascontiguousarray(candidates, dtype=np.float32)
-        n, d = X.shape
-        S = len(C)
-        batch_size = min(batch_size, S)
-        n_pend = (len(pending)
-                  if self.pending_penalty and pending is not None else 0)
-        dp = pad_dims(d)
-        # pad rows/candidates to stable multiples: a handful of jit cache
-        # entries over a whole run, not one per observation count
-        na = pad_rows(n + n_pend, 64)
-        Sp = pad_rows(S, 256)
-        Xb = np.zeros((na, dp), np.float32)
-        Xb[:n, :d] = X
-        yb = np.zeros(na, np.float32)
-        yb[:n] = y
-        if n_pend:
-            Xb[n:n + n_pend, :d] = np.asarray(pending, dtype=np.float32)
-        Cb = np.zeros((Sp, dp), np.float32)
-        Cb[:S, :d] = C
-        meta = np.array([n, n_pend, S, self.gamma], np.float32)
-        picks = fused_tpe_propose(
-            Xb, yb, Cb, meta, batch_size=batch_size, d_true=d,
-            use_pallas=self.use_pallas)
-        picks = jax.device_get(picks)  # one explicit exit sync
-        return [int(i) for i in picks]
-
-
-STRATEGIES["tpe"] = TPEStrategy

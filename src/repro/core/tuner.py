@@ -20,14 +20,14 @@ Config keys (mirroring Mango's ``conf_dict``):
   optimizer ("bayesian" | "clustering" | "random" | "tpe"),
   domain_size (None -> heuristic), mc_samples (None -> heuristic),
   seed (0), early_stopping (callable(results) -> bool),
-  checkpoint_path (None), fit_steps (40), use_pallas (False),
+  checkpoint_path (None), fit_steps (40),
   refit_every (8; full GP hyperparameter re-tune every N new observations —
-  in between, observations extend the Cholesky incrementally in O(n^2)),
+  in between, the hyperparameters stay frozen),
   scheduler (None; any ``repro.scheduler`` Scheduler — then ``objective``
   is a *per-trial* callable and the scheduler wraps it into the batch
   objective, so ``Tuner`` and ``AsyncTuner`` take the same inputs),
-  strategy_kwargs (None; dict of strategy-specific knobs forwarded to the
-  strategy constructor — e.g. ``{"gamma": 0.2}`` or
+  strategy_kwargs (None; dict of strategy-specific knobs — e.g.
+  ``{"gamma": 0.2}`` or
   ``{"pending_penalty": True}`` for ``optimizer="tpe"``, ``{"top_frac":
   0.1}`` for ``clustering``; unknown keys raise TypeError).
 """
@@ -43,8 +43,8 @@ from repro.core.optimizer import AskTellOptimizer, Trial
 DEFAULTS = dict(batch_size=1, num_iteration=20, initial_random=2,
                 optimizer="bayesian", domain_size=None, mc_samples=None,
                 seed=0, early_stopping=None, checkpoint_path=None,
-                fit_steps=40, use_pallas=False,
-                refit_every=8, scheduler=None, strategy_kwargs=None)
+                fit_steps=40, refit_every=8, scheduler=None,
+                strategy_kwargs=None)
 
 
 @dataclasses.dataclass
@@ -91,7 +91,6 @@ class Tuner:
                 domain_size=self.conf["domain_size"],
                 mc_samples=self.conf["mc_samples"],
                 fit_steps=self.conf["fit_steps"],
-                use_pallas=self.conf["use_pallas"],
                 refit_every=self.conf["refit_every"],
                 strategy_kwargs=self.conf["strategy_kwargs"])
         self.space = self.opt.space

@@ -27,7 +27,7 @@ def run(args) -> dict:
     cfg = get_config(args.arch, reduced=args.reduced)
     rt = Runtime(param_dtype=jnp.float32 if args.fp32 else jnp.bfloat16,
                  compute_dtype=jnp.float32 if args.fp32 else jnp.bfloat16,
-                 use_pallas=args.pallas)
+                 pallas=args.pallas)
     key = jax.random.PRNGKey(args.seed)
     params = init_params(key, cfg, rt)
 

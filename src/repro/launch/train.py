@@ -39,7 +39,7 @@ def build(args):
         ce_chunk=min(args.seq, 512),
         ssm_chunk=min(args.seq, 256),
         remat_policy=args.remat,
-        use_pallas=args.pallas,
+        pallas=args.pallas,
     )
     hyper = TrainHyper(
         opt=AdamWConfig(lr=args.lr, warmup_steps=args.warmup,

@@ -159,7 +159,7 @@ def attention(p: dict, x: jax.Array, cfg: ArchConfig, rt: Runtime, *,
     kv = (k, v)  # un-expanded (B, S, KV, hd) for the decode cache
     bq, bk = min(128, q.shape[1]), min(128, k.shape[1])
     divisible = q.shape[1] % bq == 0 and k.shape[1] % bk == 0
-    if rt.use_pallas and rt.sc.mesh is None and divisible:
+    if rt.pallas and rt.sc.mesh is None and divisible:
         # single-device hot path: fused flash-attention kernel (GQA-aware;
         # under a mesh the jnp path lowers through SPMD instead)
         from repro.kernels.flash_attention.ops import sdpa as flash_sdpa

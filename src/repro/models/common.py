@@ -97,7 +97,7 @@ class Runtime:
     moe_capacity_factor: float = 0.0   # 0 -> use cfg.capacity_factor
     moe_expert_parallel: bool = False  # shard expert axis over TP (EP mode)
     remat_policy: str = "full"         # none | dots | full
-    use_pallas: bool = False           # dispatch hot ops to Pallas kernels
+    pallas: bool = False               # dispatch hot ops to Pallas kernels
     z_loss: float = 1e-4
 
 

@@ -81,7 +81,7 @@ def mamba(p: dict, x: jax.Array, cfg: ArchConfig, rt: Runtime, *,
     xz = sc.constrain(xz, bs, None, sc.div(2 * di, sc.tp_axis))
     x_c, z, Abar, Bx, Cc, x_in = _ssm_inputs(p, xz, cfg, rt, batch=batch)
 
-    if rt.use_pallas and rt.sc.mesh is None and not return_state \
+    if rt.pallas and rt.sc.mesh is None and not return_state \
             and S % min(64, S) == 0 and di % min(512, di) == 0:
         from repro.kernels.ssm_scan.ops import selective_scan
         h_dot_c = selective_scan(Abar, Bx, Cc, chunk=min(64, S),

@@ -71,7 +71,7 @@ def mlstm(p: dict, x: jax.Array, cfg: ArchConfig, rt: Runtime, *,
     dh = di // nh
     q, k, v, logi, logf, z, x_m = _mlstm_qkv_gates(p, x, cfg, rt)
 
-    if rt.use_pallas and rt.sc.mesh is None and not return_state \
+    if rt.pallas and rt.sc.mesh is None and not return_state \
             and S % min(64, S) == 0:
         from repro.kernels.mlstm_chunk.ops import mlstm_mixer
         h = mlstm_mixer(q.swapaxes(1, 2).astype(jnp.float32),

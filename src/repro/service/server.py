@@ -223,7 +223,6 @@ class TuningService:
             mc_samples=cfg.get("mc_samples"),
             fit_steps=int(cfg.get("fit_steps", 40)),
             refit_every=int(cfg.get("refit_every", 8)),
-            use_pallas=bool(cfg.get("use_pallas", False)),
             strategy_kwargs=cfg.get("strategy_kwargs"))
         self.compact_every_ops = int(cfg.get("compact_every_ops", 0))
         self.compact_interval_s = float(cfg.get("compact_interval_s", 0.0))

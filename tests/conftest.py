@@ -5,7 +5,11 @@ from pathlib import Path
 # NOTE: no XLA_FLAGS here on purpose — smoke tests and benches must see the
 # single real CPU device; only the dry-run (and subprocess sharding tests)
 # force 512/8 host devices.
-sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
+ROOT = Path(__file__).resolve().parents[1]
+# the program, and the benchmark's float64 references (``bench.lib``)
+for p in (str(ROOT / "src"), str(ROOT)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402

@@ -71,7 +71,7 @@ BAD_FIXTURES = {
         def per_study(xs):
             return [jnp.exp(x) for x in xs]
         """),
-    "REPRO-J103": ("core/acquisition.py", """
+    "REPRO-J103": ("core/scoring.py", """
         import jax
 
         def make(scale):
@@ -165,7 +165,7 @@ GOOD_FIXTURES = {
             for j in range(4):
                 o_ref[j] = jnp.exp(x_ref[j])
         """),
-    "REPRO-J103": ("core/acquisition.py", """
+    "REPRO-J103": ("core/scoring.py", """
         import functools
 
         import jax

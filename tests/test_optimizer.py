@@ -1,4 +1,4 @@
-"""The ask/tell core: ledger invariants, fused pending-trial hallucination
+"""The ask/tell core: ledger invariants, pending-trial hallucination
 (single device program per pick), sync<->async parity, and kill/resume
 determinism in both execution modes."""
 import json
@@ -10,8 +10,7 @@ from scipy.stats import uniform
 
 import repro.core.gp as gp_mod
 from repro.core import AskTellOptimizer, AsyncTuner, Tuner, TunerResults
-from repro.core.strategies import (FusedHallucinationStrategy,
-                                   HallucinationStrategy)
+from repro.core.strategies import check_kwargs
 from repro.scheduler.base import TaskHandle
 
 SPACE = {"x": uniform(0, 1), "y": uniform(0, 1)}
@@ -106,41 +105,16 @@ def test_minimize_sign_handling():
     assert res.best_objective == 1.0   # smaller raw value wins
 
 
-# ---------------------------------------------- fused pending hallucination
-def test_pending_absorbed_inside_fused_program():
-    """Pending trials hallucinated in-program pick the same candidates as
-    the host-loop hallucinate + fused pick (the seed AsyncTuner path)."""
-    rng = np.random.default_rng(0)
-    X = rng.uniform(size=(20, 2)).astype(np.float32)
-    y = -((X[:, 0] - 0.6) ** 2 + (X[:, 1] - 0.4) ** 2)
-    C = rng.uniform(size=(600, 2)).astype(np.float32)
-    P = rng.uniform(size=(3, 2)).astype(np.float32)
-
-    fused = FusedHallucinationStrategy(2, 1e4, fit_steps=15)
-    picks = fused.propose(X, y, C, 4, pending=P)
-
-    host = FusedHallucinationStrategy(2, 1e4, fit_steps=15)
-    st = host.gp.observe(X, y)
-    st = host.gp.ensure_capacity(st, len(P) + 4)
-    for p in P:
-        st = host.gp.hallucinate(st, p)
-    assert picks == host.pick_from_state(st, C, 4)
-
-    ref = HallucinationStrategy(2, 1e4, fit_steps=15)
-    assert picks == ref.propose(X, y, C, 4, pending=P)
-
-
+# ---------------------------------------------------- pending hallucination
 def test_async_pick_is_single_gp_program(monkeypatch):
     """A replacement pick with k pending trials must dispatch the staged
     bank pipeline exactly once — one ``bank_absorb`` + one ``bank_pick``
     per ask, never one posterior+append program per pending trial (the
     seed's host loop).  Single-study asks route through the bank-of-one
-    engine, so the retired monolithic ``fused_propose*`` ask-path entry
-    points must never run."""
-    calls = {"bank_pick": 0, "bank_absorb": 0, "host_hallucinate": 0}
+    engine."""
+    calls = {"bank_pick": 0, "bank_absorb": 0}
     orig_pick = gp_mod.bank_pick
     orig_absorb = gp_mod.bank_absorb
-    orig_hall = gp_mod.GaussianProcess.hallucinate
 
     def count(key, orig):
         def wrapper(*a, **k):
@@ -148,17 +122,9 @@ def test_async_pick_is_single_gp_program(monkeypatch):
             return orig(*a, **k)
         return wrapper
 
-    def boom(*a, **k):
-        raise AssertionError("retired monolithic ask path was used")
-
     monkeypatch.setattr(gp_mod, "bank_pick", count("bank_pick", orig_pick))
     monkeypatch.setattr(gp_mod, "bank_absorb",
                         count("bank_absorb", orig_absorb))
-    monkeypatch.setattr(gp_mod.GaussianProcess, "hallucinate",
-                        count("host_hallucinate", orig_hall))
-    for name in ("fused_propose", "fused_propose_pending",
-                 "fused_propose_pallas", "fused_propose_pallas_pending"):
-        monkeypatch.setattr(gp_mod, name, boom)
 
     opt = AskTellOptimizer(SPACE, seed=0, **FAST)
     for t in opt.ask(4):               # random phase (no GP yet)
@@ -167,7 +133,6 @@ def test_async_pick_is_single_gp_program(monkeypatch):
     assert calls["bank_pick"] == 1 and calls["bank_absorb"] == 0
     opt.ask(2)                         # 3 pending -> ONE absorb + ONE pick
     assert calls["bank_pick"] == 2 and calls["bank_absorb"] == 1
-    assert calls["host_hallucinate"] == 0
 
 
 # ----------------------------------------------------- sync <-> async parity
@@ -261,18 +226,14 @@ def test_sync_kill_resume_via_state_dict(tmp_path):
 
 
 # ------------------- checkpoint round-trips through the hardened core
-@pytest.mark.parametrize("use_pallas", [False, True])
-def test_sync_kill_resume_with_hardened_scorer(tmp_path, use_pallas):
-    """Kill/resume replay reproduces identical picks when proposals run
-    through the unified factor-scoring core (ISSUE 5): the checkpoint's GP
-    fit schedule must replay the hardened (L, L^{-1}) append chain
-    bit-for-bit in the sync driver.  ``use_pallas=False`` additionally
-    covers the clustering strategy, which now also scores through the
-    shared core."""
-    conf = dict(optimizer="bayesian", num_iteration=5, batch_size=2,
-                seed=11, refit_every=4, use_pallas=use_pallas, **FAST)
-    if not use_pallas:
-        conf["optimizer"] = "clustering"
+@pytest.mark.parametrize("optimizer", ["bayesian", "clustering"])
+def test_sync_kill_resume_with_hardened_scorer(tmp_path, optimizer):
+    """Kill/resume replay reproduces identical picks for both pick heads
+    of the shared factor-scoring core: the checkpoint's GP fit schedule
+    must replay the hardened (L, L^{-1}) factors bit-for-bit in the sync
+    driver."""
+    conf = dict(optimizer=optimizer, num_iteration=5, batch_size=2,
+                seed=11, refit_every=4, **FAST)
     objective = lambda b: ([quad(p) for p in b], list(b))  # noqa: E731
     full = Tuner(SPACE, objective, conf).maximize()
 
@@ -287,12 +248,12 @@ def test_sync_kill_resume_with_hardened_scorer(tmp_path, use_pallas):
 
 
 def test_async_kill_resume_with_hardened_scorer(tmp_path):
-    """Async kill/resume through the Pallas factor core: in-flight trials
+    """Async kill/resume through the factor core: in-flight trials
     re-dispatch from the ledger and the replacement picks (which absorb
     pending rows via the hardened ``scoring.absorb_pending`` loop inside
     the device program) replay identically."""
     kw = dict(num_evals=8, batch_size=2, initial_random=2, seed=21,
-              use_pallas=True, **FAST)
+              **FAST)
     full = AsyncTuner(SPACE, quad, InlineScheduler(), **kw).maximize()
 
     ckpt = tmp_path / "hardened_async.json"
@@ -313,7 +274,7 @@ def test_state_dict_format_unchanged_by_scoring_core():
     stays 1, the key set is stable, and the GP snapshot still carries only
     the fit schedule (n_fit + raw log-params) — the tracked factor is a
     pure function of those, so no migration shim is needed."""
-    opt = AskTellOptimizer(SPACE, seed=0, use_pallas=True, **FAST)
+    opt = AskTellOptimizer(SPACE, seed=0, **FAST)
     for t in opt.ask(3):
         opt.tell(t.id, quad(t.params))
     opt.ask(1)
@@ -349,30 +310,39 @@ def test_tuner_accepts_scheduler_config_key():
 
 
 def test_out_of_order_tells_keep_incremental_gp_path(monkeypatch):
-    """Async completions land out of ask order; the GP history must stay
-    append-only (tell order) so incremental Cholesky appends survive and
-    full refits only happen on the refit_every schedule."""
-    fits = {"n": 0}
-    orig_fit = gp_mod.GaussianProcess.fit
+    """Async completions land out of ask order; the fits still follow the
+    refit_every schedule (a fit when an ask sees ``refit_every`` or more
+    observations since the last one), not one per tell."""
+    fits = []
+    orig_fit = gp_mod.fit_hypers_bank
 
-    def counting_fit(self, X, y):
-        fits["n"] += 1
-        return orig_fit(self, X, y)
+    def counting_fit(*a, **k):
+        fits.append(int(np.asarray(a[2]).sum()))   # observations fit on
+        return orig_fit(*a, **k)
 
-    monkeypatch.setattr(gp_mod.GaussianProcess, "fit", counting_fit)
+    monkeypatch.setattr(gp_mod, "fit_hypers_bank", counting_fit)
     rng = np.random.default_rng(0)
-    opt = AskTellOptimizer(SPACE, seed=0, mc_samples=400, fit_steps=10)
+    opt = AskTellOptimizer(SPACE, seed=0, mc_samples=400, fit_steps=10,
+                           refit_every=8)
     inflight = list(opt.ask(4))
     n_done = 0
+    seen = []                        # observations each model ask saw
     while n_done < 40:
         t = inflight.pop(rng.integers(len(inflight)))  # random completion
         opt.tell(t.id, quad(t.params))
         n_done += 1
         if n_done + len(inflight) < 40:
+            if n_done >= 2:
+                seen.append(n_done)
             inflight.extend(opt.ask(1))
-    # refit_every=8 over 40 observations -> ~5 scheduled refits; prefix
-    # instability would push this to ~19
-    assert fits["n"] <= 7
+    # the schedule, replayed over the counts the asks saw: the first
+    # model ask fits, then every ask 8 or more observations on
+    want = []
+    for k in seen:
+        if not want or k - want[-1] >= 8:
+            want.append(k)
+    assert fits == want
+    assert len(fits) == 5             # one fit per tell would be 33
 
 
 def test_objective_may_return_transformed_params():
@@ -452,8 +422,7 @@ def test_tpe_async_kill_resume_replays_proposals(tmp_path):
 def test_tpe_ask_is_single_device_program(monkeypatch):
     """Every TPE ask — pending trials included — must dispatch exactly one
     bank-serving fused device program (``fused_tpe_propose_bank``, which
-    vmaps the per-row kernel over the study axis) and never fall back to
-    the host numpy KDE."""
+    vmaps the per-row kernel over the study axis)."""
     import repro.core.tpe as tpe_mod
 
     calls = {"fused": 0}
@@ -463,12 +432,7 @@ def test_tpe_ask_is_single_device_program(monkeypatch):
         calls["fused"] += 1
         return orig(*a, **k)
 
-    def boom(*a, **k):
-        raise AssertionError("host numpy KDE path was used")
-
     monkeypatch.setattr(tpe_mod, "fused_tpe_propose_bank", counting)
-    monkeypatch.setattr(tpe_mod.TPEStrategy, "_log_kde", boom)
-    monkeypatch.setattr(tpe_mod.TPEStrategy, "propose_host", boom)
 
     opt = AskTellOptimizer(
         SPACE, optimizer="tpe", seed=0,
@@ -482,31 +446,40 @@ def test_tpe_ask_is_single_device_program(monkeypatch):
     assert calls["fused"] == 2
 
 
-def test_strategy_kwargs_forwarded_and_validated():
-    """The core forwards strategy_kwargs verbatim; unknown keys surface as
-    TypeError at first ask (the old TPEStrategy silently swallowed them)."""
+def test_strategy_kwargs_forwarded_and_validated(monkeypatch):
+    """The core forwards strategy_kwargs to the bank's TPE program;
+    unknown keys surface as TypeError at first ask (the old TPEStrategy
+    silently swallowed them)."""
+    import repro.core.tpe as tpe_mod
+
+    gammas = []
+    orig = tpe_mod.fused_tpe_propose_bank
+
+    def spy(X, y, C, meta, **k):
+        gammas.append(float(np.asarray(meta)[0, 3]))
+        return orig(X, y, C, meta, **k)
+
+    monkeypatch.setattr(tpe_mod, "fused_tpe_propose_bank", spy)
     opt = AskTellOptimizer(SPACE, optimizer="tpe", seed=0,
                            strategy_kwargs={"gamma": 0.5}, **FAST)
     for t in opt.ask(2):
         opt.tell(t.id, quad(t.params))
     opt.ask(1)
-    assert opt._strat.gamma == 0.5
-    assert opt._strat.domain_size == opt.domain_size   # no longer dropped
+    assert gammas == [0.5]
 
     bad = AskTellOptimizer(SPACE, optimizer="tpe", seed=0,
                            strategy_kwargs={"gamme": 0.5}, **FAST)
-    with pytest.raises(TypeError):   # strategy built on the first ask
+    with pytest.raises(TypeError):   # checked on the first ask
         bad.ask(1)
 
 
 def test_tpe_gamma_validation():
-    from repro.core.tpe import TPEStrategy
     with pytest.raises(ValueError):
-        TPEStrategy(2, 1e4, gamma=0.0)
+        check_kwargs("tpe", {"gamma": 0.0}, 2, 1e4)
     with pytest.raises(ValueError):
-        TPEStrategy(2, 1e4, gamma=0.6)   # good quantile capped at 0.5:
-    with pytest.raises(ValueError):      # disjoint splits -> one exp/row
-        TPEStrategy(2, 1e4, gamma=1.0)
+        check_kwargs("tpe", {"gamma": 0.6}, 2, 1e4)   # good quantile
+    with pytest.raises(ValueError):                    # capped at 0.5:
+        check_kwargs("tpe", {"gamma": 1.0}, 2, 1e4)   # disjoint splits
     with pytest.raises(ValueError):
-        TPEStrategy(0, 1e4)
-    TPEStrategy(2, 1e4, gamma=0.5)       # boundary is valid
+        check_kwargs("tpe", {}, 0, 1e4)
+    check_kwargs("tpe", {"gamma": 0.5}, 2, 1e4)       # boundary is valid

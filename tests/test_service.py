@@ -705,8 +705,8 @@ def test_chip_smoke_exits_nonzero_without_a_tpu():
 
 
 def test_chip_smoke_phases_pass_small_on_cpu(tmp_path, monkeypatch):
-    """Every phase of the smoke at a toy fleet: HTTP replies, the float64
-    reference, WAL-replay bit-equality, zero steady-state compiles."""
+    """Every phase of the smoke at a toy fleet: HTTP replies, WAL-replay
+    bit-equality, zero steady-state compiles."""
     import repro.compile_cache
     monkeypatch.setattr(repro.compile_cache, "enable_compile_cache",
                         lambda: str(tmp_path / "cache"))

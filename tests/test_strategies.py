@@ -1,107 +1,110 @@
+"""The strategy table: names, the checks on ``strategy_kwargs`` through
+every entry point that takes them, the random draw and the k-means the
+clustering head runs."""
 import numpy as np
 import pytest
+from scipy import stats
 
+from repro.core import AskTellOptimizer, StudyBank
 from repro.core.kmeans import kmeans_assign
-from repro.core.strategies import (ClusteringStrategy, HallucinationStrategy,
-                                   RandomStrategy)
+from repro.core.strategies import check_kwargs, random_picks
+from repro.service.server import TuningService
 
+SPACE = {"x": stats.uniform(0, 1), "y": stats.uniform(0, 1)}
 
-def _data(n=20, seed=0):
-    rng = np.random.default_rng(seed)
-    X = rng.uniform(size=(n, 2)).astype(np.float32)
-    y = -((X[:, 0] - 0.6) ** 2 + (X[:, 1] - 0.4) ** 2)
-    C = rng.uniform(size=(600, 2)).astype(np.float32)
-    return X, y, C
-
-
-def test_hallucination_batch_is_diverse():
-    X, y, C = _data()
-    s = HallucinationStrategy(2, 1e4, fit_steps=15)
-    picked = s.propose(X, y, C, batch_size=5)
-    assert len(set(picked)) == 5
-    pts = C[picked]
-    # hallucination must spread the batch: no two picks collapse together
-    d = np.linalg.norm(pts[:, None] - pts[None], axis=-1)
-    np.fill_diagonal(d, 1.0)
-    assert d.min() > 1e-3
-
-
-def test_clustering_batch_unique_and_spread():
-    X, y, C = _data(seed=1)
-    s = ClusteringStrategy(2, 1e4, fit_steps=15)
-    picked = s.propose(X, y, C, batch_size=5)
-    assert len(set(picked)) == 5
-
-
-def test_batch1_reduces_to_ucb_argmax():
-    X, y, C = _data(seed=2)
-    h = HallucinationStrategy(2, 1e4, fit_steps=15)
-    c = ClusteringStrategy(2, 1e4, fit_steps=15)
-    assert h.propose(X, y, C, 1)[0] == c.propose(X, y, C, 1)[0]
+# (optimizer, strategy_kwargs, the error it must raise)
+BAD = [
+    ("tpe", {"gamma": 0.6}, ValueError),
+    ("tpe", {"gamme": 0.2}, TypeError),
+    ("bayesian", {"scorer": "chol"}, TypeError),
+    ("clustering", {"scorer": "kinv_jnp"}, TypeError),
+    ("bayesian", {"gamma": 0.2}, TypeError),
+    ("hallucination_ref", {}, ValueError),
+]
 
 
 def test_random_strategy_no_gp():
-    s = RandomStrategy()
-    picked = s.propose(None, [], np.zeros((100, 2)), 8, seed=0)
+    picked = random_picks(100, 8, seed=0)
     assert len(set(picked)) == 8
 
 
 def test_random_strategy_clamps_small_candidate_set():
     """batch_size > n_candidates (tiny mc_samples override) must degrade
     gracefully instead of raising ValueError from rng.choice."""
-    s = RandomStrategy()
-    picked = s.propose(None, [], np.zeros((3, 2)), 8, seed=0)
+    picked = random_picks(3, 8, seed=0)
     assert sorted(int(p) for p in picked) == [0, 1, 2]
 
 
-def test_clustering_empty_cluster_backfill_never_duplicates():
-    """Duplicated candidate locations force k-means to leave clusters
-    empty; the backfill must never re-select an already-picked index (the
-    old ``members = rest if len(rest) else top`` path could, silently
-    collapsing the batch's spatial diversity)."""
-    X, y, _ = _data(seed=3)
-    # 3 distinct locations repeated -> k=5 clustering has >= 2 empty slots
-    base = np.array([[0.1, 0.1], [0.5, 0.5], [0.9, 0.9]], np.float32)
-    C = np.repeat(base, 7, axis=0)
-    for seed in range(4):
-        s = ClusteringStrategy(2, 1e4, fit_steps=10)
-        picked = s.propose_host(X, y, C, batch_size=5, seed=seed)
-        assert len(picked) == len(set(picked)) == 5
-        dev = ClusteringStrategy(2, 1e4, fit_steps=10)
-        picked_dev = dev.propose(X, y, C, batch_size=5, seed=seed)
-        assert len(picked_dev) == len(set(picked_dev)) == 5
+def test_strategy_kwargs_accepted_per_family():
+    check_kwargs("tpe", {"gamma": 0.5, "pending_penalty": True}, 2, 1e4)
+    check_kwargs("clustering", {"top_frac": 0.1}, 2, 1e4)
+    check_kwargs("bayesian", {}, 2, 1e4)
+    check_kwargs("random", {"anything": 1}, 2, 1e4)
 
 
-def test_clustering_propose_stays_on_device(monkeypatch):
-    """The fused clustering path must not materialize the acquisition
-    surface on host: neither the host predict adapter nor the host k-means
-    may run."""
-    import repro.core.strategies as strat_mod
-
-    def boom(*a, **k):
-        raise AssertionError("host acquisition/k-means path was used")
-
-    monkeypatch.setattr(strat_mod.ClusteringStrategy, "_predict", boom)
-    monkeypatch.setattr(strat_mod, "kmeans_assign", boom)
-    X, y, C = _data(seed=1)
-    s = ClusteringStrategy(2, 1e4, fit_steps=15)
-    picked = s.propose(X, y, C, batch_size=5, seed=0)
-    assert len(set(picked)) == 5
+@pytest.mark.parametrize("opt,kwargs,err", BAD)
+def test_bad_strategy_config_refused_by_the_optimizer(opt, kwargs, err):
+    with pytest.raises(err):
+        AskTellOptimizer(SPACE, optimizer=opt,
+                         strategy_kwargs=kwargs).ask(1)
 
 
-def test_contradictory_scorer_configs_raise():
-    """Invalid/contradictory scoring configs raise instead of silently
-    substituting a backend (matching the repo's validation convention)."""
-    with pytest.raises(ValueError, match="unknown scorer"):
-        HallucinationStrategy(2, 1e4, scorer="nope")
-    with pytest.raises(ValueError, match="conflicts"):
-        HallucinationStrategy(2, 1e4, use_pallas=True, scorer="chol")
-    with pytest.raises(ValueError, match="factor core"):
-        ClusteringStrategy(2, 1e4, scorer="chol")
-    # the defaults resolve, not raise
-    assert ClusteringStrategy(2, 1e4).scorer == "kinv_jnp"
-    assert ClusteringStrategy(2, 1e4, use_pallas=True).scorer == \
-        "kinv_pallas"
+@pytest.mark.parametrize("opt,kwargs,err", BAD)
+def test_bad_strategy_config_refused_by_the_bank(opt, kwargs, err):
+    """Refused before any study asks, in the random phase or the
+    device phase alike."""
+    if opt == "hallucination_ref":
+        with pytest.raises(err, match="unknown optimizer"):
+            StudyBank(SPACE, 2, optimizer=opt)
+        return
+    bank = StudyBank(SPACE, 2, optimizer=opt, strategy_kwargs=kwargs,
+                     mc_samples=32)
+    for p in bank.space.sample(3, np.random.default_rng(0)):
+        bank.study(1).observe_params(p, p["x"])
+    with pytest.raises(err):
+        bank.ask_all(1)
+    assert int(bank.ledger.n_trials[0]) == 0
+
+
+@pytest.mark.parametrize("opt,kwargs,err", BAD)
+def test_bad_strategy_config_refused_by_the_service_create(tmp_path, opt,
+                                                           kwargs, err):
+    """The create op is refused before it reaches the journal."""
+    svc = TuningService(tmp_path, config={
+        "space": {"x": {"uniform": [0.0, 1.0]}}, "max_studies": 2,
+        "strategy_kwargs": kwargs})
+    try:
+        with pytest.raises(err):
+            svc.create_study("s", optimizer=opt)
+        assert svc.bank.op_seq == 0 and "s" not in svc._names
+    finally:
+        svc.close()
+
+
+def _refusal(source, name, tmp_path):
+    """The error that ``name`` meets when it comes from ``source``."""
+    with pytest.raises(ValueError) as ei:
+        if source == "config":
+            TuningService(tmp_path / name, config={
+                "space": {"x": {"uniform": [0.0, 1.0]}}, "max_studies": 1,
+                "optimizer": name})
+        elif source == "journal":
+            StudyBank(SPACE, 1).apply_op(
+                {"seq": 1, "op": "create", "study": 0, "optimizer": name})
+        else:
+            sd = StudyBank(SPACE, 1).state_dict()
+            sd["strategies"] = [name]
+            StudyBank(SPACE, 1).load_state_dict(sd)
+    return str(ei.value).replace(repr(name), "NAME")
+
+
+@pytest.mark.parametrize("source", ["config", "journal", "snapshot"])
+def test_retired_optimizer_name_refused_like_any_unknown(tmp_path, source):
+    """``hallucination_ref`` (the retired numpy reference strategy) is
+    refused as any unknown name is, wherever it comes from."""
+    got = _refusal(source, "hallucination_ref", tmp_path)
+    assert "unknown optimizer" in got
+    assert got == _refusal(source, "no_such_optimizer", tmp_path)
 
 
 def test_kmeans_partitions():

@@ -134,3 +134,25 @@ if HAVE_HYPOTHESIS:
 else:
     def test_observation_order_invariance():
         pytest.importorskip("hypothesis")
+
+
+def test_checkpoint_resume_reproduces_remaining_proposals(tmp_path):
+    """A Tuner resumed from checkpoint_path proposes the same remaining
+    configurations as an uninterrupted run (the bank's fit schedule is
+    restored from the checkpointed ``gp`` entry)."""
+    space = {"x": uniform(0, 1), "y": uniform(0, 1)}
+    conf = dict(optimizer="bayesian", num_iteration=6, batch_size=2, seed=7,
+                refit_every=4, mc_samples=1200, fit_steps=15)
+    full = Tuner(space, serial_objective, conf).maximize()
+
+    ckpt = tmp_path / "t.json"
+    conf_i = {**conf, "checkpoint_path": str(ckpt), "num_iteration": 3}
+    Tuner(space, serial_objective, conf_i).maximize()
+    assert json.loads(ckpt.read_text())["iteration"] == 3
+    resumed = Tuner(space, serial_objective,
+                    {**conf_i, "num_iteration": 6}).maximize()
+    assert resumed.iterations == 6
+    full_xy = [(p["x"], p["y"]) for p in full.params_tried]
+    res_xy = [(p["x"], p["y"]) for p in resumed.params_tried]
+    assert res_xy == full_xy
+    assert resumed.objective_values == full.objective_values
